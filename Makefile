@@ -20,10 +20,10 @@ test:
 # The second pass re-runs the shard-marked tests under a FORCED 4-device
 # host platform so multi-device shard_map parity never silently skips on
 # single-device CI hosts (XLA_FLAGS must be set before jax imports, so it
-# needs its own interpreter).
+# needs its own interpreter).  That pass is CPU-only by construction.
 test-fast:
 	$(PP) REPRO_FUZZ_EXAMPLES=3 $(PY) -m pytest -q -m "not slow"
-	$(PP) REPRO_FUZZ_EXAMPLES=3 \
+	$(PP) REPRO_FUZZ_EXAMPLES=3 JAX_PLATFORMS=cpu \
 	  XLA_FLAGS=--xla_force_host_platform_device_count=4 \
 	  $(PY) -m pytest -q -m shard
 

@@ -8,7 +8,9 @@ Every driver goes through the ONE experiment API
 """
 from __future__ import annotations
 
+import os
 import time
+from pathlib import Path
 
 from repro.core.params import NetworkSpec
 from repro.sim.events import NetSim
@@ -34,6 +36,23 @@ TRANSPORT_CFG = {
 
 TRANSPORTS = ["strack", "strack-obl", "roce", "roce4"]
 FABRIC_TRANSPORTS = list(TRANSPORT_CFG)
+
+#: Home of JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR
+#: is unset: one fixed directory inside the checkout, so a later run of any
+#: entry point finds what an earlier one compiled.
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[1] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX places the cache there
+    itself and nothing is overridden; otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`.  Call before the first compile."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return jax.config.jax_compilation_cache_dir
 
 
 def transport_config(transport: str, backend: str = "fabric",

@@ -22,8 +22,9 @@ plus a **scale axis** (``n_hosts`` vs warp ticks/sec, compile seconds and
 XLA compile-time ceiling is tracked across PRs instead of rediscovered,
 and a **kernel-backend axis**: every scenario's warp run is repeated per
 ``FabricConfig.kernel_backend`` (``jnp`` inline stages vs the Pallas
-hot-path kernels; ``pallas_interpret`` on CPU hosts, compiled ``pallas``
-on TPU/GPU) under a bit-exact parity gate, and the scale axis carries a
+hot-path kernels; ``pallas_interpret`` on CPU hosts — compiled
+``pallas`` does not lower for the TPU yet and is swept only when named)
+under a bit-exact parity gate, and the scale axis carries a
 ``kernel_backend`` tag per point — so BENCH_fabric.json tracks the
 kernel trajectory across PRs.  Select backends explicitly with
 ``--kernel-backends jnp,pallas_interpret``.
@@ -61,6 +62,7 @@ import time
 
 import jax
 
+from benchmarks.common import use_compile_cache
 from repro.core.params import NetworkSpec
 from repro.sim import fabric
 from repro.sim.topology import full_bisection
@@ -101,13 +103,16 @@ _KERNEL_PARITY_KEYS = ("max_fct", "avg_fct", "drops", "pauses",
 
 
 def default_kernel_backends() -> list:
-    """Kernel backends the bench sweeps by default: the inline jnp path
+    """Kernel backends the bench sweeps by default: the inline jnp path,
     plus interpret-mode Pallas on CPU hosts (same XLA ops underneath, so
-    it is cheap and bit-exact-checkable anywhere) or compiled Pallas on
-    TPU/GPU."""
+    it is cheap and bit-exact-checkable there).  Compiled ``"pallas"`` is
+    not offered: the TPU lowering refuses its kernels (the ranker's
+    dynamic slices, the fused cores' scatters — see
+    kernels/fabric_kernels.py), so it runs only when asked for by name,
+    and then fails loudly."""
     if jax.default_backend() == "cpu":
         return ["jnp", "pallas_interpret"]
-    return ["jnp", "pallas"]
+    return ["jnp"]
 
 
 def canonical_scenarios() -> dict:
@@ -344,7 +349,9 @@ def bench_scale_axis(repeats: int = 1, kernel_backends: list = ()) -> list:
 #: ``validate_report`` walks this so a malformed report (hand-edited,
 #: truncated write, schema drift) fails the gate as loudly as a parity
 #: failure does.
-_SCHEMA_META = {"utc": str, "jax": str, "backend": str, "platform": str}
+_SCHEMA_META = {"utc": str, "jax": str, "backend": str, "platform": str,
+                "device_platform": str, "device_kind": str,
+                "device_count": int}
 #: ``program_builds_total`` (scenario level) is the whole-scenario build
 #: count across all modes — a diagnostic.  The retrace-regression hook
 #: reads the per-mode ``program_builds`` inside ``warp``/``dense``
@@ -542,6 +549,9 @@ def bench_all(out_path: str = "BENCH_fabric.json",
             "jax": jax.__version__,
             "backend": jax.default_backend(),
             "platform": platform.platform(),
+            "device_platform": jax.devices()[0].platform,
+            "device_kind": jax.devices()[0].device_kind,
+            "device_count": len(jax.devices()),
         },
         "scenarios": {},
     }
@@ -666,7 +676,7 @@ def main() -> None:
     ap.add_argument("--kernel-backends", metavar="LIST", default=None,
                     help="comma list of kernel backends to sweep "
                          "(default: jnp + pallas_interpret on CPU, "
-                         "jnp + pallas elsewhere); 'jnp' alone skips "
+                         "jnp alone elsewhere); 'jnp' alone skips "
                          "the kernels axis")
     ap.add_argument("--profile", metavar="DIR",
                     help="trace one warm warp scenario under "
@@ -675,6 +685,7 @@ def main() -> None:
                     choices=sorted(canonical_scenarios()),
                     help="which canonical scenario --profile runs")
     args = ap.parse_args()
+    use_compile_cache()
     backends = (None if args.kernel_backends is None
                 else [b for b in args.kernel_backends.split(",") if b])
     if args.check:

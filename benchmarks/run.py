@@ -112,22 +112,6 @@ def main() -> None:
                      f"cdf_spread={r['cdf_spread']:.3f};"
                      f"done={r['finished']}/{r['total']}")
 
-    # Roofline table (ours): summarize cached dry-run cells
-    try:
-        import glob
-        import json
-        cells = sorted(glob.glob("experiments/dryrun/*__pod.json"))
-        for fn in cells:
-            d = json.load(open(fn))
-            r = d["roofline"]
-            emit(f"roofline_{d['arch']}_{d['shape']}",
-                 r["bound_time_s"] * 1e6,
-                 f"dominant={r['dominant']};"
-                 f"flops_ratio={r['model_flops_ratio']:.2f};"
-                 f"roofline_frac={r['roofline_fraction']:.3f}")
-    except Exception as e:  # noqa: BLE001
-        print(f"# roofline table unavailable: {e}")
-
 
 if __name__ == "__main__":
     main()

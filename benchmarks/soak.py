@@ -29,6 +29,7 @@ import time
 
 import numpy as np
 
+from benchmarks.common import use_compile_cache
 from repro.core.params import NetworkSpec
 from repro.obs.metrics import MetricsRegistry, parse_prometheus, \
     render_prometheus
@@ -184,6 +185,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="CI smoke: small fleet, 3 epochs of 2000 ticks")
     args = ap.parse_args()
+    use_compile_cache()
     if args.smoke:
         epochs = args.epochs or 3
         sys.exit(run_soak(args.out, epochs, seed=args.seed,

@@ -10,7 +10,8 @@ Pallas kernels, selected by ``FabricConfig.kernel_backend``:
 
   * ``"jnp"`` (default) — no Pallas: the fabric calls the stage *core*
     functions inline and XLA fuses them as before.
-  * ``"pallas"`` — compiled Pallas kernels (real TPU/GPU backends).
+  * ``"pallas"`` — compiled Pallas kernels.  They do not lower for the
+    TPU yet (see the caveats below), so this backend fails loudly there.
   * ``"pallas_interpret"`` — Pallas interpret mode: the kernel bodies run
     as ordinary XLA ops on any backend (CPU CI), preserving the kernel
     call structure and ref semantics without a Mosaic/Triton compile.
@@ -36,11 +37,20 @@ Integer ranks are deterministic, so algorithm independence still yields
 bit-identical results.
 
 Compiled-mode caveats (see docs/performance.md "Kernel backends"): the
-fused-stage kernels are single-block — every operand must fit the
-target's kernel memory (VMEM on TPU) — and the transition kernel traces
-protocol ``lax.cond`` / segment ops inside the kernel body, which Mosaic
-supports only on recent TPU generations.  Interpret mode has neither
-restriction and is the only mode exercised on CPU CI.
+Pallas TPU lowering refuses these kernels, as a compile for a TPU v5e
+shows (tests/test_tpu_compile.py pins both refusals):
+
+  * the ranker raises ``NotImplementedError: Unimplemented primitive in
+    Pallas TPU lowering: dynamic_slice`` — its block sweep reads and
+    writes blocks with ``dynamic_index_in_dim`` / ``dynamic_update_slice``;
+  * the fused serve/enqueue and transition cores raise the same error for
+    ``scatter`` — their ``.at[].set/add`` updates.
+
+Nothing falls back: ``kernel_backend="pallas"`` raises that error, and the
+benchmark no longer offers it by default.  Beyond lowering, the fused
+stages are single-block (every operand must fit VMEM) and trace protocol
+``lax.cond`` / segment ops inside the kernel body.  Interpret mode has
+none of these limits and is the only mode that runs, on CPU CI.
 """
 from __future__ import annotations
 

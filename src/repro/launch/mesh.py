@@ -4,21 +4,21 @@ A function, not a module constant, so importing never touches jax device
 state.  Single pod: 16x16 = 256 chips ("data", "model").  Multi-pod:
 2x16x16 = 512 chips ("pod", "data", "model") — the "pod" axis carries the
 inter-pod (Ethernet/DCN) data parallelism that STrack accelerates.
-
-Mesh construction goes through ``repro.compat`` so the same call works on
-JAX versions with and without ``axis_types`` / ``AxisType``.
 """
 from __future__ import annotations
 
-from ..compat import make_mesh as _compat_make_mesh
+import jax
+
+
+def make_mesh(shape, axes):
+    """Mesh over the visible devices with Auto axis types (e.g. (1,1) smoke
+    meshes for tests/examples)."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh for tests/examples (e.g. (1,1) smoke meshes)."""
-    return _compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)

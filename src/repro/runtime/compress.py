@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
-
 
 def _quantize(x):
     amax = jnp.maximum(jnp.max(jnp.abs(x)), 1e-12)
@@ -56,7 +54,7 @@ def hierarchical_int8_psum(x, mesh, *, pod_axis: str = "pod",
         return total.astype(xs.dtype)
 
     axes = (pod_axis,) + tuple(intra_axes)
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=P((*axes,)),     # all reduce axes stacked on dim 0
         out_specs=P((*axes,)),
@@ -78,8 +76,8 @@ def two_stage_allreduce_bytes_demo(mesh, shape=(1024, 1024)):
     def plain(v):
         def body(vs):
             return jax.lax.psum(vs, axes)
-        return shard_map(body, mesh=mesh, in_specs=P((*axes,)),
-                         out_specs=P((*axes,)), check_vma=False)(v)
+        return jax.shard_map(body, mesh=mesh, in_specs=P((*axes,)),
+                             out_specs=P((*axes,)), check_vma=False)(v)
 
     def hier(v):
         return hierarchical_int8_psum(v, mesh, pod_axis="pod",

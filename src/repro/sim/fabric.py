@@ -106,7 +106,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import compat
 from ..core import reliability as rel
 from ..kernels.fabric_kernels import (flow_transition_kernel, iota1,
                                       rank_in_queue_core,
@@ -576,19 +575,24 @@ class FabricConfig:
     # state by flow block; popped heads and NIC offers cross pods through
     # explicit all_gather exchanges while all small per-queue vectors stay
     # replicated, so results are bit-exact vs the unsharded program.
-    # CPU-only hosts test this via
-    # ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
+    # The mesh takes the first ``shard`` visible devices: TPU chips on a
+    # multi-chip host (perm8k at shard=4 on a v5e 2x2 is bit-exact vs
+    # shard=0, ``chip_smoke.py --four-chips``), or the forced host devices
+    # of ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` on the CPU.
     shard: int = 0
     # Kernel backend for the scan body's three hot stages (fused ring
     # service+enqueue, the sort-free enqueue ranker, per-flow protocol
     # transitions — see kernels/fabric_kernels.py):
-    #   "jnp"              the stage cores run inline, XLA-fused (default)
-    #   "pallas"           compiled Pallas kernels (real TPU/GPU backends)
+    #   "jnp"              the stage cores run inline, XLA-fused (default;
+    #                      the path that runs on the TPU)
+    #   "pallas"           compiled Pallas kernels; the TPU lowering
+    #                      refuses them (dynamic_slice in the ranker,
+    #                      scatter in the fused cores), so this raises there
     #   "pallas_interpret" Pallas interpret mode: the kernel path's call
     #                      structure + bit-exactness on any backend (CPU
     #                      CI; tests/test_fabric_kernels.py)
-    # Both Pallas modes are bit-exact vs "jnp" (same stage cores, gated
-    # by the differential-fuzz suite).  Single-device only: shard > 1
+    # Interpret mode is bit-exact vs "jnp" (same stage cores, gated by
+    # the differential-fuzz suite).  Single-device only: shard > 1
     # keeps its inline jnp stages (all_gather exchanges cannot live
     # inside a kernel body).
     kernel_backend: str = "jnp"
@@ -869,7 +873,8 @@ def _make_program(topo: FatTree, n_flows: int, n_ticks: int,
         if n_dev < DP:
             raise ValueError(
                 f"cfg.shard={DP} needs {DP} devices but only {n_dev} are "
-                f"visible; on CPU hosts export "
+                f"visible: run on a host with {DP} chips, or on the CPU "
+                f"export "
                 f"XLA_FLAGS=--xla_force_host_platform_device_count={DP}")
     net = cfg.net
     proto, kmin_p, kmax_p, _ = _make_protocol(cfg)
@@ -2074,7 +2079,8 @@ def _make_program(topo: FatTree, n_flows: int, n_ticks: int,
         # the two explicit all_gather exchanges above are the only
         # cross-pod traffic.
         Pspec = jax.sharding.PartitionSpec
-        mesh = compat.make_mesh((DP,), ("pod",))
+        mesh = jax.make_mesh((DP,), ("pod",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         fl_s, rcv_s = jax.eval_shape(
             proto.init,
             jax.ShapeDtypeStruct((NL,), jnp.int32),
@@ -2097,7 +2103,7 @@ def _make_program(topo: FatTree, n_flows: int, n_ticks: int,
             corrupt_drops=rep, tx_rows=rep, win_retx=rep)
         m_spec = ({"warp_trips": rep, "end_tick": rep}
                   if cfg.time_warp else {})
-        sharded = compat.shard_map(
+        sharded = jax.shard_map(
             body, mesh=mesh, in_specs=(rep,) * 8,
             out_specs=(st_spec, m_spec), check_vma=False)
 

@@ -332,15 +332,17 @@ class RunConfig:
     active_cap: Optional[int] = None
     # Fabric sharding: partition the program over this many devices with
     # shard_map (queues by switch block, flows by block; the inter-pod hop
-    # is an explicit all_gather exchange).  0/1 = single-device.  On CPU,
-    # force a device mesh with XLA_FLAGS=--xla_force_host_platform_
+    # is an explicit all_gather exchange).  0/1 = single-device.  The
+    # mesh takes the first N chips of a multi-chip TPU host; on the CPU,
+    # force N host devices with XLA_FLAGS=--xla_force_host_platform_
     # device_count=N.  Bit-exact vs unsharded; requires trace_every=0.
     shard: int = 0
     # Fabric kernel backend for the scan body's hot stages: "jnp"
-    # (inline, XLA-fused — the default), "pallas" (compiled Pallas
-    # kernels; real TPU/GPU) or "pallas_interpret" (Pallas interpret
-    # mode, runs anywhere incl. CPU CI).  All three are bit-exact
-    # (tests/test_fabric_kernels.py + the fuzz suite's kernel leg);
+    # (inline, XLA-fused — the default and the path that runs on the
+    # TPU), "pallas" (compiled Pallas kernels, which the TPU lowering
+    # refuses: it raises there) or "pallas_interpret" (Pallas interpret
+    # mode, runs anywhere incl. CPU CI, bit-exact vs jnp per
+    # tests/test_fabric_kernels.py + the fuzz suite's kernel leg);
     # single-device only (shard <= 1).
     kernel_backend: str = "jnp"
     # Chaos schedule (sim/faults.py): time-varying link/NIC flaps,

@@ -11,8 +11,10 @@ from benchmarks.perf import (check_report_file, regression_problems,
                              validate_report)
 
 GOOD = {
-    "meta": {"utc": "2026-07-31T00:00:00Z", "jax": "0.4.35",
-             "backend": "cpu", "platform": "Linux"},
+    "meta": {"utc": "2026-07-31T00:00:00Z", "jax": "0.9.0",
+             "backend": "tpu", "platform": "Linux",
+             "device_platform": "tpu", "device_kind": "TPU v5 lite",
+             "device_count": 1},
     "scenarios": {
         "perm1024": {
             "n_ticks": 9000, "n_hosts": 1024, "n_msgs": 1024,
@@ -104,6 +106,15 @@ def test_schema_violations_are_flagged():
     bad = copy.deepcopy(GOOD)
     bad["scenarios"]["perm1024"]["n_ticks"] = "9000"
     assert any("n_ticks" in p for p in validate_report(bad))
+    # every report names the device it ran on
+    for k in ("device_platform", "device_kind", "device_count"):
+        bad = copy.deepcopy(GOOD)
+        del bad["meta"][k]
+        assert any(f"meta: missing key {k!r}" in p
+                   for p in validate_report(bad))
+    bad = copy.deepcopy(GOOD)
+    bad["meta"]["device_count"] = "1"
+    assert any("meta.device_count" in p for p in validate_report(bad))
     # malformed scale-axis point
     bad = copy.deepcopy(GOOD)
     del bad["scale_axis"][0]["compile_s"]

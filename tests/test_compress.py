@@ -20,11 +20,10 @@ mesh = make_mesh((2, 4, 2), ("pod", "data", "model"))
 x = jax.random.normal(jax.random.PRNGKey(0), (16, 64), jnp.float32)
 xs = jax.device_put(x, jax.sharding.NamedSharding(mesh, P(("pod", "data"))))
 got = jax.jit(lambda v: hierarchical_int8_psum(v, mesh))(xs)
-from repro.compat import shard_map
-want = jax.jit(shard_map(lambda v: jax.lax.psum(v, ("pod", "data")),
-                         mesh=mesh, in_specs=P(("pod", "data")),
-                         out_specs=P(("pod", "data")),
-                         check_vma=False))(xs)
+want = jax.jit(jax.shard_map(lambda v: jax.lax.psum(v, ("pod", "data")),
+                             mesh=mesh, in_specs=P(("pod", "data")),
+                             out_specs=P(("pod", "data")),
+                             check_vma=False))(xs)
 err = float(jnp.max(jnp.abs(got - want))) / float(jnp.max(jnp.abs(want)))
 assert err < 0.02, err          # int8 quantisation error only
 
@@ -41,6 +40,7 @@ print("OK", err, int8_hop)
 def test_hierarchical_int8_psum_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices; never a chip
     out = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                          capture_output=True, text=True, timeout=300,
                          cwd=os.path.dirname(os.path.dirname(__file__)))

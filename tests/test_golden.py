@@ -85,6 +85,20 @@ def _snapshot(res: dict) -> dict:
     return {k: res[k] for k in GOLDEN_KEYS if k in res}
 
 
+def golden_mismatches(snap: dict, want: dict) -> list:
+    """``(key, got, want)`` for every pinned key that breaks the snapshot
+    rule: exact ints, 1e-6 relative on floats (chip_smoke.py applies the
+    same rule to the goldens run on the chip)."""
+    bad = []
+    for k, v in sorted(want.items()):
+        got = snap[k]
+        ok = (got == pytest.approx(v, rel=1e-6) if isinstance(v, float)
+              else got == v)
+        if not ok:
+            bad.append((k, got, v))
+    return bad
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_golden_fct(case, update_golden, golden_dir):
     sc, cfg = CASES[case]()
@@ -102,9 +116,5 @@ def test_golden_fct(case, update_golden, golden_dir):
     assert set(snap) == set(want), (
         f"{case}: summary keys changed {sorted(want)} -> {sorted(snap)}; "
         f"regenerate the goldens if intentional")
-    for k, v in sorted(want.items()):
-        got = snap[k]
-        if isinstance(v, float):
-            assert got == pytest.approx(v, rel=1e-6), (case, k, got, v)
-        else:
-            assert got == v, (case, k, got, v)
+    assert not golden_mismatches(snap, want), (
+        case, golden_mismatches(snap, want))

@@ -194,8 +194,8 @@ def test_elastic_reshard_restore(tmp_path):
     params, _ = small_state()
     d = str(tmp_path)
     ckpt.save(d, 1, {"params": params})
-    from repro.compat import make_mesh
-    mesh = make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     shardings = {"params": to_shardings(param_specs(params, mesh), mesh)}
     restored, _ = ckpt.restore(d, 1, {"params": params}, shardings=shardings)
     for a, b in zip(jax.tree.leaves(restored["params"]),
