@@ -18,8 +18,10 @@ the inline jnp stages.  The default run has two phases:
 
 ``--four-chips`` runs only ``perm8k`` with ``shard=4`` against the same
 scenario unsharded on one of those chips (bit-exact on the parity keys),
-then traces a few warp trips of the same sharded program and reports the
-collective time per trip on each chip.
+then traces a few warp trips of the same sharded program and reduces the
+trace with the benchmark's reduction (``bench/scopes.py``): collective
+and device time per trip, time per ``tick()`` stage, and idle time by
+host span, averaged over the chips.
 
 Each run prints one line: scenario, hosts, messages, flows, ticks, warp
 trips, cold and warm seconds (both end in a host fetch of the results)
@@ -41,18 +43,18 @@ ROOT = Path(__file__).resolve().parent
 sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
 
 import jax  # noqa: E402
+from jax.profiler import TraceAnnotation  # noqa: E402
 
+from bench import scopes, tracing  # noqa: E402
 from benchmarks import perf  # noqa: E402
 from benchmarks.common import use_compile_cache  # noqa: E402
+from repro.obs import spans  # noqa: E402
 from repro.sim import fabric  # noqa: E402
 from repro.sim.workloads import (RunConfig, _scenario_ticks,  # noqa: E402
                                  permutation_scenario, run)
 from tests.test_golden import CASES, _snapshot, golden_mismatches  # noqa: E402
 
 TRACE_DIR = ROOT / "traces" / "chip_smoke_four_chips"
-#: HLO op-name prefixes of the cross-chip exchanges in a device trace.
-COLLECTIVES = ("all-gather", "all-reduce", "collective-permute",
-               "reduce-scatter", "all-to-all")
 
 
 def check(ok: bool, what) -> None:
@@ -133,31 +135,22 @@ def phase_scale() -> None:
     check(res["unfinished"] == 0 and res["drops"] == 0, res)
 
 
-def collective_seconds(trace_dir: Path) -> dict:
-    """Per TPU plane of the newest trace under ``trace_dir``:
-    ``{line: (collective seconds, collective events, distinct collective
-    instructions, events)}`` for the synchronous and the async op lines.
-    Event names are HLO instruction texts (``%all-gather.3 = ...``)."""
-    from jax.profiler import ProfileData
-    path = max(trace_dir.rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
-    out = {}
-    for plane in ProfileData.from_file(str(path)).planes:
-        if not plane.name.startswith("/device:TPU:"):
-            continue
-        lines = {}
-        for line in plane.lines:
-            if line.name not in ("XLA Ops", "Async XLA Ops"):
-                continue
-            ns, names, n_ev = 0, [], 0
-            for ev in line.events:
-                n_ev += 1
-                instr = ev.name.split(" = ", 1)[0].lstrip("%")
-                if instr.startswith(COLLECTIVES):
-                    ns += ev.duration_ns
-                    names.append(instr)
-            lines[line.name] = (ns * 1e-9, len(names), len(set(names)), n_ev)
-        out[plane.name] = lines
-    return out
+def traced_run(sc, cfg: RunConfig) -> tuple[dict, dict]:
+    """``(summary, trace numbers)`` of one run captured by the profiler
+    inside a ``bench.slice`` span, reduced by ``bench/scopes.py`` with the
+    program's own spans."""
+    with spans.recording() as rec:
+        jax.profiler.start_trace(str(TRACE_DIR))
+        try:
+            with TraceAnnotation(tracing.SLICE):
+                t0 = time.perf_counter()
+                res = run(sc, cfg)
+                t1 = time.perf_counter()
+        finally:
+            jax.profiler.stop_trace()
+    path = max(TRACE_DIR.rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
+    host = [(s.start, s.end, s.name) for s in rec.spans]
+    return res, scopes.reduce(path, host + [(t0, t1, tracing.SLICE)])
 
 
 def phase_four_chips() -> None:
@@ -183,19 +176,19 @@ def phase_four_chips() -> None:
     short_cfg = RunConfig(backend="fabric", shard=4,
                           n_ticks=_scenario_ticks(sc, four_cfg), **kw)
     b0 = fabric.program_builds
-    with jax.profiler.trace(str(TRACE_DIR)):
-        trips = run(short, short_cfg)["warp_trips"]
+    res, r = traced_run(short, short_cfg)
     check(fabric.program_builds == b0, "the traced run rebuilt the program")
-    per_dev = collective_seconds(TRACE_DIR)
-    for dev, lines in sorted(per_dev.items()):
-        for name, (coll, n_coll, n_instr, n_ev) in sorted(lines.items()):
-            print(f"[four_chips] trace {dev} {name!r}: trips={trips} "
-                  f"collective_s={coll} collective_events={n_coll} "
-                  f"collective_instructions={n_instr} events={n_ev} "
-                  f"collective_s_per_trip={coll / trips}", flush=True)
-    busy = [d for d, lines in per_dev.items()
-            if lines.get("XLA Ops", (0, 0, 0, 0))[3] > 0]
-    check(len(busy) == 4, ("expected work on 4 chips", per_dev))
+    trips = res["warp_trips"]
+    print(f"[four_chips] trace: chips={r['devices']} trips={trips} "
+          f"counted_trips={r['trips']} busy_s={r['busy_s']} "
+          f"collective_s={r['collective_s']} "
+          f"collective_ms_per_trip={1e3 * r['collective_s'] / trips} "
+          f"device_ms_per_trip={r['device_ms_per_trip']} "
+          f"stages_ms_per_trip={json.dumps(r['stages'])} "
+          f"idle_s_by_span={json.dumps(r['idle_by_span'])}", flush=True)
+    check(r["devices"] == 4 and r["trips"] == trips,
+          ("expected every trip on 4 chips", r["devices"], r["trips"],
+           trips))
 
 
 def main() -> None:

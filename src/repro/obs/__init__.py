@@ -10,7 +10,10 @@ text exposition format, and dumps it to a ``.prom`` file that
 (``BENCH_history.jsonl``) and gates regressions against the best run in
 history, not just the last one.
 
-Everything here is pure stdlib: no prometheus_client, no jax.
+Everything above is pure stdlib: no prometheus_client, no jax.
+``repro.obs.spans`` (imported on its own, it needs jax) holds the
+program's own host spans and compile counters: the fabric program's
+time, not the simulated network's.
 """
 from .metrics import (MetricsRegistry, parse_prometheus,  # noqa: F401
                       render_prometheus)

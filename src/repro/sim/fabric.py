@@ -115,6 +115,7 @@ from ..core.params import (ACK_WIRE_BYTES, NetworkSpec, RoCEParams,
                            STrackParams, make_roce_params,
                            make_strack_params)
 from ..core.reliability import SackMsg
+from ..obs import spans
 from .faults import (FaultSpec, build_fault_data, duty_open, fault_u01,
                      validate_faults)
 from .dcqcn_fab import (RoceFabParams, empty_roce_msgs, init_roce_flow,
@@ -1300,13 +1301,15 @@ def _make_program(topo: FatTree, n_flows: int, n_ticks: int,
             else:
                 def rank_among(flag):
                     return _rank_in_queue(cand_qid, flag, Q)
-            rank_v = rank_among(cand_valid)
+            with jax.named_scope("fabric.queues.rank"):
+                rank_v = rank_among(cand_valid)
             occ = qsize1[cand_qid] + rank_v
             dropped = cand_valid & (
                 ((~cand.probe) & (occ >= data_drop_pkts))
                 | (occ >= hard_pkts))
             accept = cand_valid & (~dropped)
-            rank_a = rank_among(accept)
+            with jax.named_scope("fabric.queues.rank"):
+                rank_a = rank_among(accept)
             pos = (qhead1[cand_qid] + qsize1[cand_qid] + rank_a) % cap
             flat_idx = jnp.where(accept, cand_qid * cap + pos, Q * cap)
             q1 = PktQ(*[f.reshape(-1).at[flat_idx].set(v)
@@ -1344,580 +1347,598 @@ def _make_program(topo: FatTree, n_flows: int, n_ticks: int,
             """
             now = t.astype(jnp.float32) * tick_us
 
-            # ---- 0. dependency gate: a message is sendable the tick its
-            # pending-dep counter reaches zero AND its arrival tick has
-            # come (deps-free, arrival-0 traces: always) ------------------
-            sendable_msg = (st.pending <= 0) & (arrival <= t)
-            sendable = sendable_msg[dep.msg_of_flow]
-            msg_release_tick = jnp.where(
-                sendable_msg & (st.msg_release_tick < 0),
-                t.astype(jnp.int32), st.msg_release_tick)
+            with jax.named_scope("fabric.gate"):
+                # ---- 0. dependency gate: a message is sendable the tick its
+                # pending-dep counter reaches zero AND its arrival tick has
+                # come (deps-free, arrival-0 traces: always) ------------------
+                sendable_msg = (st.pending <= 0) & (arrival <= t)
+                sendable = sendable_msg[dep.msg_of_flow]
+                msg_release_tick = jnp.where(
+                    sendable_msg & (st.msg_release_tick < 0),
+                    t.astype(jnp.int32), st.msg_release_tick)
 
-            # ---- 0b. PFC effective-pause masks: the decision from PD
-            # ticks ago (pause frames propagate one hop upstream), read
-            # by both the NIC gate (transport) and the serve step -------
-            if pfc:
-                if PD > 0:
-                    eff = st.pfc_line[t % PD]
-                    eff_nic = eff[:NH]
-                    eff_sd = eff[NH:NH + TS].reshape(S, T)
-                    eff_up = eff[NH + TS:].reshape(T, S)
-                else:
-                    eff_nic, eff_sd, eff_up = (st.paused_nic,
-                                               st.paused_sd,
-                                               st.paused_up)
-                paused_row = jnp.concatenate(
-                    [eff_up.reshape(-1), eff_sd.reshape(-1),
-                     jnp.zeros((NH,), bool)])
-            else:
-                # None leaves vanish under pytree flattening, so the
-                # kernel wrappers pass these through untouched
-                eff_nic = paused_row = None
-
-            # ---- 0c. chaos masks: per-tick link state from the traced
-            # fault schedule (sim/faults.py).  Entry counts are static,
-            # so every branch below vanishes from fault-free programs;
-            # inactive windows (t outside [t0, t1)) scatter into the
-            # trash row, so inert entries are exact no-ops.
-            ti = t.astype(jnp.int32)
-            if F_ROW > 0:
-                f_act = (fd.flap_row_t0 <= ti) & (ti < fd.flap_row_t1)
-                row_down = jnp.zeros((Q + 1,), bool).at[
-                    jnp.where(f_act, fd.flap_row, Q)].set(True)[:Q]
-            else:
-                row_down = None
-            if F_NIC > 0:
-                n_act = (fd.flap_nic_t0 <= ti) & (ti < fd.flap_nic_t1)
-                nic_down = jnp.zeros((NH + 1,), bool).at[
-                    jnp.where(n_act, fd.flap_nic, NH)].set(True)[:NH]
-            else:
-                nic_down = None
-            if F_DEG > 0:
-                d_act = (fd.deg_t0 <= ti) & (ti < fd.deg_t1)
-                d_closed = d_act & (~duty_open(ti, fd.deg_num))
-                row_duty = jnp.ones((Q + 1,), bool).at[
-                    jnp.where(d_closed, fd.deg_row, Q)].set(False)[:Q]
-            else:
-                row_duty = None
-            if F_COR > 0:
-                c_act = (fd.cor_t0 <= ti) & (ti < fd.cor_t1)
-                row_cor_p = jnp.zeros((Q + 1,), jnp.float32).at[
-                    jnp.where(c_act, fd.cor_row, Q)].max(fd.cor_p)[:Q]
-                fseed = fd.seed
-            else:
-                row_cor_p = None
-                fseed = None
-            if F_UP > 0:
-                # flapped uplinks leave the ECMP candidate set for the
-                # flap window.  Live spines in ascending order via a
-                # stable argsort on the down-mask — exactly the static
-                # live_list construction, so with no flap active this is
-                # bit-identical to at.ecmp_spine.
-                u_act = (fd.flap_up_t0 <= ti) & (ti < fd.flap_up_t1)
-                up_down = jnp.zeros((TS + 1,), bool).at[
-                    jnp.where(u_act, fd.flap_up, TS)].set(
-                    True)[:TS].reshape(T, S)
-                live_now = at.live_mask & (~up_down)
-                n_live_now = jnp.maximum(
-                    jnp.sum(live_now, axis=1).astype(jnp.int32), 1)
-                live_order = jnp.argsort(~live_now, axis=1,
-                                         stable=True).astype(jnp.int32)
-
-                def pick_spine(s_, d_, e_):
-                    tor_ = s_ // HPT
-                    k_ = ecmp_mix(s_, d_, e_) % n_live_now[tor_]
-                    return live_order[tor_, k_]
-            else:
-                pick_spine = at.ecmp_spine
-
-            # ---- 1. transport lanes: due ACKs, timers, sends (kernel 3)
-            # Three equivalent lane formulations of the same per-flow
-            # steps (all bit-exact in observables — the fuzz suite pins
-            # them against each other):
-            #   * dense (default): lanes are all N flows,
-            #   * active-set: lanes are the <= A flows that are released
-            #     and not done, compacted with a fill-value nonzero (the
-            #     ascending index order preserves candidate order, hence
-            #     ranks, drops and ring layout),
-            #   * sharded: this pod's NL flow lanes; NIC offers cross pods
-            #     through an all_gather so arbitration stays global.
-            # The transport stage reads + clears return-pipe slot t % H
-            # BEFORE the receivers (stage 3 below) write slot
-            # (t + D[flow]) % H — always a different slot, so this is
-            # order-independent.
-            cur = t % H
-            overflow = jnp.zeros((), jnp.int32)
-            if DP > 1:
-                due = jax.tree.map(lambda a: a[cur], st.pipe)
-                flows_l = jax.vmap(lambda f, m: proto.on_ack(f, m, now))(
-                    st.flows, due)
-                pipe = st.pipe._replace(valid=st.pipe.valid.at[cur].set(
-                    jnp.zeros((NL,), bool)))
-                flows_t_l, probe_tx_l = jax.lax.cond(
-                    (t % cfg.timer_every) == 0,
-                    lambda f: timers_of(f, now),
-                    lambda f: (f, empty_tx(NL)), flows_l)
-                probe_tx = gath(probe_tx_l)
-                probe_valid = probe_tx.valid & sendable
+            with jax.named_scope("fabric.pfc"):
+                # ---- 0b. PFC effective-pause masks: the decision from PD
+                # ticks ago (pause frames propagate one hop upstream), read
+                # by both the NIC gate (transport) and the serve step -------
                 if pfc:
-                    blocked = probe_tx.valid & eff_nic[src]
-                    flows_l = _bwhere(fslice(sendable & (~blocked)),
-                                      flows_t_l, flows_l)
-                    probe_valid = probe_valid & (~blocked)
+                    if PD > 0:
+                        eff = st.pfc_line[t % PD]
+                        eff_nic = eff[:NH]
+                        eff_sd = eff[NH:NH + TS].reshape(S, T)
+                        eff_up = eff[NH + TS:].reshape(T, S)
+                    else:
+                        eff_nic, eff_sd, eff_up = (st.paused_nic,
+                                                   st.paused_sd,
+                                                   st.paused_up)
+                    paused_row = jnp.concatenate(
+                        [eff_up.reshape(-1), eff_sd.reshape(-1),
+                         jnp.zeros((NH,), bool)])
                 else:
-                    flows_l = _bwhere(fslice(sendable), flows_t_l,
-                                      flows_l)
-                flows_sent_l, tx_l = jax.vmap(
-                    lambda f: proto.next_packet(f, now))(flows_l)
-                tx = gath(tx_l)
-                can_tx = tx.valid & sendable
-                score = jnp.where(can_tx, (iota_n - t) % NR, NR)
-                best = jax.ops.segment_min(score, src, num_segments=NH)
-                sel = can_tx & (score == best[src])
+                    # None leaves vanish under pytree flattening, so the
+                    # kernel wrappers pass these through untouched
+                    eff_nic = paused_row = None
+
+            with jax.named_scope("fabric.faults"):
+                # ---- 0c. chaos masks: per-tick link state from the traced
+                # fault schedule (sim/faults.py).  Entry counts are static,
+                # so every branch below vanishes from fault-free programs;
+                # inactive windows (t outside [t0, t1)) scatter into the
+                # trash row, so inert entries are exact no-ops.
+                ti = t.astype(jnp.int32)
+                if F_ROW > 0:
+                    f_act = (fd.flap_row_t0 <= ti) & (ti < fd.flap_row_t1)
+                    row_down = jnp.zeros((Q + 1,), bool).at[
+                        jnp.where(f_act, fd.flap_row, Q)].set(True)[:Q]
+                else:
+                    row_down = None
+                if F_NIC > 0:
+                    n_act = (fd.flap_nic_t0 <= ti) & (ti < fd.flap_nic_t1)
+                    nic_down = jnp.zeros((NH + 1,), bool).at[
+                        jnp.where(n_act, fd.flap_nic, NH)].set(True)[:NH]
+                else:
+                    nic_down = None
+                if F_DEG > 0:
+                    d_act = (fd.deg_t0 <= ti) & (ti < fd.deg_t1)
+                    d_closed = d_act & (~duty_open(ti, fd.deg_num))
+                    row_duty = jnp.ones((Q + 1,), bool).at[
+                        jnp.where(d_closed, fd.deg_row, Q)].set(False)[:Q]
+                else:
+                    row_duty = None
+                if F_COR > 0:
+                    c_act = (fd.cor_t0 <= ti) & (ti < fd.cor_t1)
+                    row_cor_p = jnp.zeros((Q + 1,), jnp.float32).at[
+                        jnp.where(c_act, fd.cor_row, Q)].max(fd.cor_p)[:Q]
+                    fseed = fd.seed
+                else:
+                    row_cor_p = None
+                    fseed = None
+                if F_UP > 0:
+                    # flapped uplinks leave the ECMP candidate set for the
+                    # flap window.  Live spines in ascending order via a
+                    # stable argsort on the down-mask — exactly the static
+                    # live_list construction, so with no flap active this is
+                    # bit-identical to at.ecmp_spine.
+                    u_act = (fd.flap_up_t0 <= ti) & (ti < fd.flap_up_t1)
+                    up_down = jnp.zeros((TS + 1,), bool).at[
+                        jnp.where(u_act, fd.flap_up, TS)].set(
+                        True)[:TS].reshape(T, S)
+                    live_now = at.live_mask & (~up_down)
+                    n_live_now = jnp.maximum(
+                        jnp.sum(live_now, axis=1).astype(jnp.int32), 1)
+                    live_order = jnp.argsort(~live_now, axis=1,
+                                             stable=True).astype(jnp.int32)
+
+                    def pick_spine(s_, d_, e_):
+                        tor_ = s_ // HPT
+                        k_ = ecmp_mix(s_, d_, e_) % n_live_now[tor_]
+                        return live_order[tor_, k_]
+                else:
+                    pick_spine = at.ecmp_spine
+
+            with jax.named_scope("fabric.transport"):
+                # ---- 1. transport lanes: due ACKs, timers, sends (kernel 3)
+                # Three equivalent lane formulations of the same per-flow
+                # steps (all bit-exact in observables — the fuzz suite pins
+                # them against each other):
+                #   * dense (default): lanes are all N flows,
+                #   * active-set: lanes are the <= A flows that are released
+                #     and not done, compacted with a fill-value nonzero (the
+                #     ascending index order preserves candidate order, hence
+                #     ranks, drops and ring layout),
+                #   * sharded: this pod's NL flow lanes; NIC offers cross pods
+                #     through an all_gather so arbitration stays global.
+                # The transport stage reads + clears return-pipe slot t % H
+                # BEFORE the receivers (stage 3 below) write slot
+                # (t + D[flow]) % H — always a different slot, so this is
+                # order-independent.
+                cur = t % H
+                overflow = jnp.zeros((), jnp.int32)
+                if DP > 1:
+                    due = jax.tree.map(lambda a: a[cur], st.pipe)
+                    flows_l = jax.vmap(lambda f, m: proto.on_ack(f, m, now))(
+                        st.flows, due)
+                    pipe = st.pipe._replace(valid=st.pipe.valid.at[cur].set(
+                        jnp.zeros((NL,), bool)))
+                    flows_t_l, probe_tx_l = jax.lax.cond(
+                        (t % cfg.timer_every) == 0,
+                        lambda f: timers_of(f, now),
+                        lambda f: (f, empty_tx(NL)), flows_l)
+                    probe_tx = gath(probe_tx_l)
+                    probe_valid = probe_tx.valid & sendable
+                    if pfc:
+                        blocked = probe_tx.valid & eff_nic[src]
+                        flows_l = _bwhere(fslice(sendable & (~blocked)),
+                                          flows_t_l, flows_l)
+                        probe_valid = probe_valid & (~blocked)
+                    else:
+                        flows_l = _bwhere(fslice(sendable), flows_t_l,
+                                          flows_l)
+                    flows_sent_l, tx_l = jax.vmap(
+                        lambda f: proto.next_packet(f, now))(flows_l)
+                    tx = gath(tx_l)
+                    can_tx = tx.valid & sendable
+                    score = jnp.where(can_tx, (iota_n - t) % NR, NR)
+                    best = jax.ops.segment_min(score, src, num_segments=NH)
+                    sel = can_tx & (score == best[src])
+                    if pfc:
+                        sel = sel & (~eff_nic[src])
+                    flows = _bwhere(fslice(sel), flows_sent_l, flows_l)
+                    lane_flow, lane_src, lane_dst = iota_n, src, dst
+                    lane_same, lane_stor = same_tor, src_tor
+                    lane_fix, lane_rr = fixed_ent, st.obl_rr
+                    lane_idx, L = iota_n, N
+                elif A:
+                    # active set: released, not-yet-done flows (ascending
+                    # flow index; fill lanes read/write the trash row).  The
+                    # compaction + overflow check stay outside the core
+                    # (nonzero's static-size fill semantics); the gathered
+                    # transitions run inside it.
+                    done_prev = jax.vmap(proto.done)(st.flows)
+                    act_mask = sendable & (~done_prev)
+                    act_idx = jnp.nonzero(
+                        act_mask, size=A, fill_value=N)[0].astype(jnp.int32)
+                    lane_ok = act_idx < N
+                    act_clip = jnp.minimum(act_idx, N - 1)
+                    overflow = (jnp.sum(act_mask) > A).astype(jnp.int32)
+                    pipe_cur = jax.tree.map(lambda a: a[cur], st.pipe)
+                    (flows, tx, probe_tx, probe_valid, sel, can_tx,
+                     done_lane) = _trans(
+                        active_trans_core,
+                        (st.flows, pipe_cur, act_idx, eff_nic, src, t))
+                    pipe = st.pipe._replace(valid=st.pipe.valid.at[cur].set(
+                        jnp.zeros((N,), bool)))
+                    lane_flow, lane_src = act_clip, src[act_clip]
+                    lane_dst = dst[act_clip]
+                    lane_same, lane_stor = (same_tor[act_clip],
+                                            src_tor[act_clip])
+                    lane_fix, lane_rr = (fixed_ent[act_clip],
+                                         st.obl_rr[act_clip])
+                    lane_idx, L = act_idx, A
+                else:
+                    due = jax.tree.map(lambda a: a[cur], st.pipe)
+                    flows, tx, probe_tx, probe_valid, sel, can_tx = _trans(
+                        dense_trans_core,
+                        (st.flows, due, sendable, eff_nic, src, t))
+                    pipe = st.pipe._replace(valid=st.pipe.valid.at[cur].set(
+                        jnp.zeros((N,), bool)))
+                    lane_flow, lane_src, lane_dst = iota_n, src, dst
+                    lane_same, lane_stor = same_tor, src_tor
+                    lane_fix, lane_rr = fixed_ent, st.obl_rr
+                    lane_idx, L = iota_n, N
+
+            with jax.named_scope("fabric.route"):
+                if not proto.uses_spray:
+                    ent = tx.entropy
+                    ent_probe = probe_tx.entropy
+                    obl_rr = st.obl_rr
+                else:
+                    # lb_mode is a traced scalar (LB_MODES index) so sweeps can
+                    # vmap spray modes through ONE compiled program; the
+                    # selects below are index arithmetic, not extra queue work.
+                    is_obl = lb_code == 1
+                    is_fix = lb_code == 2
+                    ent_obl = (lane_rr + 1) % cfg.max_paths
+                    ent = jnp.where(is_obl, ent_obl,
+                                    jnp.where(is_fix, lane_fix, tx.entropy))
+                    ent_probe = jnp.where(
+                        is_obl, ent_obl,
+                        jnp.where(is_fix, lane_fix, probe_tx.entropy))
+                    if A:
+                        obl_rr = _set_rows(
+                            st.obl_rr, jnp.where(is_obl & sel, lane_idx, N),
+                            ent_obl, N)
+                    else:
+                        obl_rr = jnp.where(is_obl & sel, ent_obl, st.obl_rr)
+
+                spine = pick_spine(lane_src, lane_dst, ent)
+                inj_q = jnp.where(lane_same, 2 * TS + lane_dst,
+                                  lane_stor * S + spine)
+                spine_p = pick_spine(lane_src, lane_dst, ent_probe)
+                inj_qp = jnp.where(lane_same, 2 * TS + lane_dst,
+                                   lane_stor * S + spine_p)
+
+            with jax.named_scope("fabric.faults"):
+                # retransmit attempts COMMITTED this tick (before any NIC
+                # blackhole: the attempt happened even into a dead cable) —
+                # attributed to active flap windows below
+                if FW > 0:
+                    rtx_n = jnp.sum(sel & tx.is_rtx).astype(jnp.int32)
+                bh_nic = jnp.zeros((), jnp.int32)
+                if nic_down is not None:
+                    # host->ToR uplink down: the flow commits its send state
+                    # (the NIC transmitted into a dead cable) but the packet
+                    # never becomes an enqueue candidate — the sender learns
+                    # via silence, then RTO / SACK / go-back-N
+                    ln_down = nic_down[lane_src]
+                    bh_nic = (jnp.sum(sel & ln_down)
+                              + jnp.sum(probe_valid & ln_down)
+                              ).astype(jnp.int32)
+                    sel = sel & (~ln_down)
+                    probe_valid = probe_valid & (~ln_down)
+
+            with jax.named_scope("fabric.queues"):
+                # ---- 2. fused ring service + enqueue (kernels 1 + 2) -------
+                if DP > 1:
+                    # Inline jnp: the inter-pod hop — each pod pops its own
+                    # ring rows' heads and the [~Q x 7 scalar] head fields
+                    # cross pods in one all_gather; on enqueue each pod
+                    # writes only the ring rows it owns (the accept /
+                    # position math is replicated, so every pod agrees).
+                    qs = st.qsize[:Q]
+                    if pfc:
+                        has = (qs > 0) & (~paused_row)
+                    else:
+                        has = qs > 0
+                    qhead_pad = jnp.pad(st.qhead, (0, QR - (Q + 1)))
+                    hidx_l = jax.lax.dynamic_slice_in_dim(
+                        qhead_pad, qoff, QRL) % cap
+                    pop_l = PktQ(*[f[jnp.arange(QRL), hidx_l]
+                                   for f in st.q])
+                    pop = PktQ(*[a[:Q] for a in gath(pop_l)])
+                    has = has & (pop.ready <= t)
+                    if row_duty is not None:
+                        has = has & row_duty
+                    residual = jnp.maximum(qs - 1, 0).astype(jnp.float32)
+                    frac = jnp.clip((residual - kmin_p)
+                                    / jnp.maximum(kmax_p - kmin_p, 1e-9),
+                                    0.0, 1.0)
+                    dither = jnp.abs(jnp.sin(
+                        t.astype(jnp.float32) * 12.9898
+                        + qrows.astype(jnp.float32) * 78.233))
+                    mark = has & (~pop.probe) & (frac > dither * 0.999)
+                    ecn_out = pop.ecn | mark
+                    served = has.astype(jnp.int32)
+                    qhead = st.qhead.at[:Q].add(served)
+                    qsize = st.qsize.at[:Q].add(-served)
+                    # chaos blackhole/corruption — replicated math, identical
+                    # on every pod (see serve_enqueue_core for semantics)
+                    surv = has
+                    bh_add = jnp.zeros((), jnp.int32)
+                    cor_add = jnp.zeros((), jnp.int32)
+                    if row_down is not None:
+                        bh_add = jnp.sum(has & row_down).astype(jnp.int32)
+                        surv = surv & (~row_down)
+                    if row_cor_p is not None:
+                        u = fault_u01(fseed, qrows, ti, pop.psn)
+                        corrupt = surv & (~pop.probe) & (u < row_cor_p)
+                        cor_add = jnp.sum(corrupt).astype(jnp.int32)
+                        surv = surv & (~corrupt)
+                    fclip = jnp.clip(pop.flow, 0, N - 1)
+                    pop_bytes = wire_bytes(pop.flow, pop.psn, pop.probe)
+                    adv_tgt = jnp.where(
+                        is_up_row, TS + spine_of_row * T + dst_tor[fclip],
+                        2 * TS + dst[fclip])[:2 * TS]
+                    adv_valid = surv[:2 * TS]
+                    cand_qid = jnp.concatenate([adv_tgt, inj_q, inj_qp])
+                    cand_valid = jnp.concatenate(
+                        [adv_valid, sel, probe_valid])
+                    now_l = jnp.full((L,), now, jnp.float32)
+                    zb, ob = jnp.zeros((L,), bool), jnp.ones((L,), bool)
+                    cand = PktQ(
+                        flow=jnp.concatenate(
+                            [pop.flow[:2 * TS], lane_flow, lane_flow]),
+                        psn=jnp.concatenate(
+                            [pop.psn[:2 * TS], tx.psn, probe_tx.psn]),
+                        ts=jnp.concatenate(
+                            [pop.ts[:2 * TS], now_l, now_l]),
+                        probe=jnp.concatenate(
+                            [pop.probe[:2 * TS], zb, ob]),
+                        ecn=jnp.concatenate([ecn_out[:2 * TS], zb, zb]),
+                        ent=jnp.concatenate(
+                            [pop.ent[:2 * TS], ent, ent_probe]),
+                        ready=jnp.full((2 * TS + 2 * L,), 0, jnp.int32)
+                        + t + 1 + K,
+                        spine=jnp.concatenate(
+                            [pop.spine[:2 * TS], spine, spine_p]))
+                    cand_bytes = jnp.concatenate([
+                        pop_bytes[:2 * TS],
+                        wire_bytes(lane_flow, tx.psn, zb),
+                        wire_bytes(lane_flow, probe_tx.psn, ob)])
+                    M = 2 * TS + 2 * L
+                    if M <= 256:
+                        tril = jnp.tril(jnp.ones((M, M), bool), k=-1)
+                        same_q = cand_qid[:, None] == cand_qid[None, :]
+
+                        def rank_among(flag):
+                            return jnp.sum(same_q & flag[None, :] & tril,
+                                           axis=1).astype(jnp.int32)
+                    else:
+                        def rank_among(flag):
+                            return _rank_in_queue(cand_qid, flag, Q)
+                    with jax.named_scope("fabric.queues.rank"):
+                        rank_v = rank_among(cand_valid)
+                    occ = qsize[cand_qid] + rank_v
+                    dropped = cand_valid & (
+                        ((~cand.probe) & (occ >= data_drop_pkts))
+                        | (occ >= hard_pkts))
+                    accept = cand_valid & (~dropped)
+                    with jax.named_scope("fabric.queues.rank"):
+                        rank_a = rank_among(accept)
+                    pos = (qhead[cand_qid] + qsize[cand_qid] + rank_a) % cap
+                    ownq = accept & (cand_qid >= qoff) \
+                        & (cand_qid < qoff + QRL)
+                    flat_idx = jnp.where(
+                        ownq, (cand_qid - qoff) * cap + pos, QRL * cap)
+
+                    def _wrow(f, v):
+                        flat = f.reshape(-1)
+                        pad1 = jnp.zeros((1,), f.dtype)
+                        out = jnp.concatenate([flat, pad1], 0).at[flat_idx]
+                        return out.set(v)[:QRL * cap].reshape(QRL, cap)
+
+                    q = PktQ(*[_wrow(f, v) for f, v in zip(st.q, cand)])
+                    added = jax.ops.segment_sum(
+                        accept.astype(jnp.int32),
+                        jnp.where(accept, cand_qid, Q), num_segments=Q + 1)
+                    qsize = (qsize + added).at[Q].set(0)
+                    qhead = qhead.at[Q].set(0)
+                    drops = st.drops + jnp.sum(dropped).astype(jnp.int32)
+                else:
+                    (q, qhead, qsize, pop, has, surv, ecn_out, pop_bytes,
+                     cand_qid, cand_bytes, accept, drops_add, bh_add,
+                     cor_add) = _serve(
+                        serve_enqueue_core,
+                        (st.q, st.qhead, st.qsize, paused_row, dst,
+                         dst_tor, total_pkts, tail_b, lane_flow, tx.psn,
+                         probe_tx.psn, ent, ent_probe, spine, spine_p, sel,
+                         probe_valid, inj_q, inj_qp, row_down, row_duty,
+                         row_cor_p, fseed, t))
+                    fclip = jnp.clip(pop.flow, 0, N - 1)
+                    drops = st.drops + drops_add
+
+            with jax.named_scope("fabric.receive"):
+                # ---- 3. deliveries -> per-flow receivers (one host = one q)
+                # (surv, not has: blackholed/corrupted packets left their
+                # buffer but never arrive)
+                del_has = surv[2 * TS:]
+                del_flow = fclip[2 * TS:]
+                slot_del = (t + dflow[del_flow]) % H
+                if DP > 1:
+                    # receiver + return-pipe state live on the flow-owner
+                    # pod: every pod walks the global delivery rows but
+                    # gathers / commits only the flows it owns (trash row
+                    # otherwise)
+                    own = del_has & (del_flow >= foff) \
+                        & (del_flow < foff + NL)
+                    lrow = jnp.where(own, del_flow - foff, NL)
+                    rrows = _gather_rows(st.rcv, lrow, NL)
+                    commit, fidx, n_lanes = own, lrow, NL
+                else:
+                    rrows = jax.tree.map(lambda a: a[del_flow], st.rcv)
+                    commit, fidx, n_lanes = del_has, del_flow, N
+                rnew, sack = jax.vmap(
+                    lambda r, psn, sz, ecn, ent_, ts, pb: proto.on_data(
+                        r, psn, sz, ecn, ent_, ts, pb, now))(
+                    rrows, pop.psn[2 * TS:], pop_bytes[2 * TS:],
+                    ecn_out[2 * TS:], pop.ent[2 * TS:],
+                    pop.ts[2 * TS:], pop.probe[2 * TS:])
+                rnew = _bwhere(commit, rnew, rrows)
+                rcv = _scatter_rows(st.rcv, rnew,
+                                    jnp.where(commit, fidx, n_lanes),
+                                    n_lanes)
+                delivered = _scatter_add(
+                    st.delivered,
+                    jnp.where(del_has & (~pop.probe[2 * TS:]), del_flow, N),
+                    pop_bytes[2 * TS:], N)
+                # ECN observability: marked data packets counted at host
+                # delivery (outside the kernel cores, so identical across
+                # every lane formulation and kernel backend; warp-safe —
+                # skipped ticks deliver nothing)
+                ecn_add = jnp.sum(del_has & ecn_out[2 * TS:]
+                                  & (~pop.probe[2 * TS:])).astype(jnp.int32)
+
+                # write emitted messages into the return pipe at slot
+                # t + D[flow]: each flow's ACK rides its own reverse path
+                # (never the slot the transport stage cleared this tick:
+                # 1 <= D[flow] <= H - 2)
+                sack_valid = sack.valid & commit
+                pipe = _scatter_pipe(pipe, sack._replace(valid=sack_valid),
+                                     slot_del, fidx, sack_valid, H, n_lanes)
+
+            with jax.named_scope("fabric.pfc"):
+                # ---- 6b. PFC: per-ingress accounting + pause/resume masks
+                # Ingress attribution is derivable per packet: a packet's
+                # port at any switch follows from (flow src/dst, queue row,
+                # entropy), so the counters are maintained incrementally
+                # without storing a port field in the ring.  Accounting is
+                # per-packet WIRE bytes: odd tail packets and 64B probes
+                # count their real size, not a whole MTU (``events.Switch``
+                # semantics).
                 if pfc:
-                    sel = sel & (~eff_nic[src])
-                flows = _bwhere(fslice(sel), flows_sent_l, flows_l)
-                lane_flow, lane_src, lane_dst = iota_n, src, dst
-                lane_same, lane_stor = same_tor, src_tor
-                lane_fix, lane_rr = fixed_ent, st.obl_rr
-                lane_idx, L = iota_n, N
-            elif A:
-                # active set: released, not-yet-done flows (ascending
-                # flow index; fill lanes read/write the trash row).  The
-                # compaction + overflow check stay outside the core
-                # (nonzero's static-size fill semantics); the gathered
-                # transitions run inside it.
-                done_prev = jax.vmap(proto.done)(st.flows)
-                act_mask = sendable & (~done_prev)
-                act_idx = jnp.nonzero(
-                    act_mask, size=A, fill_value=N)[0].astype(jnp.int32)
-                lane_ok = act_idx < N
-                act_clip = jnp.minimum(act_idx, N - 1)
-                overflow = (jnp.sum(act_mask) > A).astype(jnp.int32)
-                pipe_cur = jax.tree.map(lambda a: a[cur], st.pipe)
-                (flows, tx, probe_tx, probe_valid, sel, can_tx,
-                 done_lane) = _trans(
-                    active_trans_core,
-                    (st.flows, pipe_cur, act_idx, eff_nic, src, t))
-                pipe = st.pipe._replace(valid=st.pipe.valid.at[cur].set(
-                    jnp.zeros((N,), bool)))
-                lane_flow, lane_src = act_clip, src[act_clip]
-                lane_dst = dst[act_clip]
-                lane_same, lane_stor = (same_tor[act_clip],
-                                        src_tor[act_clip])
-                lane_fix, lane_rr = (fixed_ent[act_clip],
-                                     st.obl_rr[act_clip])
-                lane_idx, L = act_idx, A
-            else:
-                due = jax.tree.map(lambda a: a[cur], st.pipe)
-                flows, tx, probe_tx, probe_valid, sel, can_tx = _trans(
-                    dense_trans_core,
-                    (st.flows, due, sendable, eff_nic, src, t))
-                pipe = st.pipe._replace(valid=st.pipe.valid.at[cur].set(
-                    jnp.zeros((N,), bool)))
-                lane_flow, lane_src, lane_dst = iota_n, src, dst
-                lane_same, lane_stor = same_tor, src_tor
-                lane_fix, lane_rr = fixed_ent, st.obl_rr
-                lane_idx, L = iota_n, N
+                    # dequeues leaving a switch buffer
+                    f_up, f_sd, f_hd = (fclip[:TS], fclip[TS:2 * TS],
+                                        fclip[2 * TS:])
+                    ing_host = _scatter_add(
+                        st.ing_host, jnp.where(has[:TS], src[f_up], NH),
+                        -pop_bytes[:TS], NH)
+                    sd_i = jnp.arange(TS, dtype=jnp.int32)
+                    sd_s = sd_i // T   # spine of spine_down row TS + s*T + t
+                    up_flat = st.ing_up.reshape(-1)
+                    up_flat = _scatter_add(
+                        up_flat,
+                        jnp.where(has[TS:2 * TS],
+                                  src_tor[f_sd] * S + sd_s, TS),
+                        -pop_bytes[TS:2 * TS], TS)
+                    # the spine that handed the packet down is the ring's
+                    # injection-time spine lane — re-deriving it from ECMP
+                    # would diverge once fault schedules make the candidate
+                    # masks time-varying
+                    pkt_spine = pop.spine[2 * TS:]
+                    hd_same = same_tor[f_hd]
+                    served_hd = has[2 * TS:]
+                    ing_host = _scatter_add(
+                        ing_host,
+                        jnp.where(served_hd & hd_same, src[f_hd], NH),
+                        -pop_bytes[2 * TS:], NH)
+                    sd_flat = st.ing_sd.reshape(-1)
+                    sd_flat = _scatter_add(
+                        sd_flat,
+                        jnp.where(served_hd & (~hd_same),
+                                  pkt_spine * T + host_tor, TS),
+                        -pop_bytes[2 * TS:], TS)
+                    # enqueues entering a switch buffer
+                    # t*S+s of the source row
+                    up_i = jnp.arange(TS, dtype=jnp.int32)
+                    up_flat = _scatter_add(
+                        up_flat, jnp.where(accept[:TS], up_i, TS),
+                        cand_bytes[:TS], TS)
+                    sd_flat = _scatter_add(
+                        sd_flat, jnp.where(accept[TS:2 * TS], sd_i, TS),
+                        cand_bytes[TS:2 * TS], TS)
+                    acc_data = accept[2 * TS:2 * TS + L]
+                    acc_probe = accept[2 * TS + L:]
+                    ing_host = _scatter_add(
+                        ing_host, jnp.where(acc_data, lane_src, NH),
+                        cand_bytes[2 * TS:2 * TS + L], NH)
+                    ing_host = _scatter_add(
+                        ing_host, jnp.where(acc_probe, lane_src, NH),
+                        cand_bytes[2 * TS + L:], NH)
+                    ing_sd = sd_flat.reshape(S, T)
+                    ing_up = up_flat.reshape(T, S)
 
-            if not proto.uses_spray:
-                ent = tx.entropy
-                ent_probe = probe_tx.entropy
-                obl_rr = st.obl_rr
-            else:
-                # lb_mode is a traced scalar (LB_MODES index) so sweeps can
-                # vmap spray modes through ONE compiled program; the
-                # selects below are index arithmetic, not extra queue work.
-                is_obl = lb_code == 1
-                is_fix = lb_code == 2
-                ent_obl = (lane_rr + 1) % cfg.max_paths
-                ent = jnp.where(is_obl, ent_obl,
-                                jnp.where(is_fix, lane_fix, tx.entropy))
-                ent_probe = jnp.where(
-                    is_obl, ent_obl,
-                    jnp.where(is_fix, lane_fix, probe_tx.entropy))
-                if A:
-                    obl_rr = _set_rows(
-                        st.obl_rr, jnp.where(is_obl & sel, lane_idx, N),
-                        ent_obl, N)
+                    # byte-accurate shared-buffer occupancy (served bytes out,
+                    # accepted bytes in) for the dynamic threshold
+                    qbytes = st.qbytes.at[:Q].add(
+                        -jnp.where(has, pop_bytes, 0.0))
+                    add_b = jax.ops.segment_sum(
+                        jnp.where(accept, cand_bytes, 0.0),
+                        jnp.where(accept, cand_qid, Q), num_segments=Q + 1)
+                    qbytes = (qbytes + add_b).at[Q].set(0.0)
+                    qsz_b = qbytes[:Q]
+                    tor_occ = (qsz_b[:TS].reshape(T, S).sum(1)
+                               + qsz_b[2 * TS:].reshape(T, HPT).sum(1))
+                    spine_occ = qsz_b[TS:2 * TS].reshape(S, T).sum(1)
+                    a = cfg.pfc_alpha
+                    xoff_tor = a * jnp.maximum(buffer_b - tor_occ, 0.0) \
+                        / (1 + a)
+                    xoff_spine = a * jnp.maximum(buffer_b - spine_occ, 0.0) \
+                        / (1 + a)
+
+                    # the gate chains on the switch's DECISION state; the
+                    # effective (upstream) state lags it by the pause-frame
+                    # propagation delay via the pfc_line ring
+                    paused_nic = pfc_gate(st.paused_nic, ing_host,
+                                          xoff_tor[host_tor], cfg.pfc_xon_frac)
+                    paused_sd = pfc_gate(st.paused_sd, ing_sd,
+                                         xoff_tor[None, :], cfg.pfc_xon_frac)
+                    paused_up = pfc_gate(st.paused_up, ing_up,
+                                         xoff_spine[None, :], cfg.pfc_xon_frac)
+                    pauses = st.pauses + (
+                        jnp.sum(paused_nic & ~st.paused_nic)
+                        + jnp.sum(paused_sd & ~st.paused_sd)
+                        + jnp.sum(paused_up & ~st.paused_up)).astype(jnp.int32)
+                    if PD > 0:
+                        dec = jnp.concatenate(
+                            [paused_nic, paused_sd.reshape(-1),
+                             paused_up.reshape(-1)])
+                        pfc_line = st.pfc_line.at[t % PD].set(dec)
+                    else:
+                        pfc_line = st.pfc_line
                 else:
-                    obl_rr = jnp.where(is_obl & sel, ent_obl, st.obl_rr)
-
-            spine = pick_spine(lane_src, lane_dst, ent)
-            inj_q = jnp.where(lane_same, 2 * TS + lane_dst,
-                              lane_stor * S + spine)
-            spine_p = pick_spine(lane_src, lane_dst, ent_probe)
-            inj_qp = jnp.where(lane_same, 2 * TS + lane_dst,
-                               lane_stor * S + spine_p)
-
-            # retransmit attempts COMMITTED this tick (before any NIC
-            # blackhole: the attempt happened even into a dead cable) —
-            # attributed to active flap windows below
-            if FW > 0:
-                rtx_n = jnp.sum(sel & tx.is_rtx).astype(jnp.int32)
-            bh_nic = jnp.zeros((), jnp.int32)
-            if nic_down is not None:
-                # host->ToR uplink down: the flow commits its send state
-                # (the NIC transmitted into a dead cable) but the packet
-                # never becomes an enqueue candidate — the sender learns
-                # via silence, then RTO / SACK / go-back-N
-                ln_down = nic_down[lane_src]
-                bh_nic = (jnp.sum(sel & ln_down)
-                          + jnp.sum(probe_valid & ln_down)
-                          ).astype(jnp.int32)
-                sel = sel & (~ln_down)
-                probe_valid = probe_valid & (~ln_down)
-
-            # ---- 2. fused ring service + enqueue (kernels 1 + 2) -------
-            if DP > 1:
-                # Inline jnp: the inter-pod hop — each pod pops its own
-                # ring rows' heads and the [~Q x 7 scalar] head fields
-                # cross pods in one all_gather; on enqueue each pod
-                # writes only the ring rows it owns (the accept /
-                # position math is replicated, so every pod agrees).
-                qs = st.qsize[:Q]
-                if pfc:
-                    has = (qs > 0) & (~paused_row)
-                else:
-                    has = qs > 0
-                qhead_pad = jnp.pad(st.qhead, (0, QR - (Q + 1)))
-                hidx_l = jax.lax.dynamic_slice_in_dim(
-                    qhead_pad, qoff, QRL) % cap
-                pop_l = PktQ(*[f[jnp.arange(QRL), hidx_l]
-                               for f in st.q])
-                pop = PktQ(*[a[:Q] for a in gath(pop_l)])
-                has = has & (pop.ready <= t)
-                if row_duty is not None:
-                    has = has & row_duty
-                residual = jnp.maximum(qs - 1, 0).astype(jnp.float32)
-                frac = jnp.clip((residual - kmin_p)
-                                / jnp.maximum(kmax_p - kmin_p, 1e-9),
-                                0.0, 1.0)
-                dither = jnp.abs(jnp.sin(
-                    t.astype(jnp.float32) * 12.9898
-                    + qrows.astype(jnp.float32) * 78.233))
-                mark = has & (~pop.probe) & (frac > dither * 0.999)
-                ecn_out = pop.ecn | mark
-                served = has.astype(jnp.int32)
-                qhead = st.qhead.at[:Q].add(served)
-                qsize = st.qsize.at[:Q].add(-served)
-                # chaos blackhole/corruption — replicated math, identical
-                # on every pod (see serve_enqueue_core for semantics)
-                surv = has
-                bh_add = jnp.zeros((), jnp.int32)
-                cor_add = jnp.zeros((), jnp.int32)
-                if row_down is not None:
-                    bh_add = jnp.sum(has & row_down).astype(jnp.int32)
-                    surv = surv & (~row_down)
-                if row_cor_p is not None:
-                    u = fault_u01(fseed, qrows, ti, pop.psn)
-                    corrupt = surv & (~pop.probe) & (u < row_cor_p)
-                    cor_add = jnp.sum(corrupt).astype(jnp.int32)
-                    surv = surv & (~corrupt)
-                fclip = jnp.clip(pop.flow, 0, N - 1)
-                pop_bytes = wire_bytes(pop.flow, pop.psn, pop.probe)
-                adv_tgt = jnp.where(
-                    is_up_row, TS + spine_of_row * T + dst_tor[fclip],
-                    2 * TS + dst[fclip])[:2 * TS]
-                adv_valid = surv[:2 * TS]
-                cand_qid = jnp.concatenate([adv_tgt, inj_q, inj_qp])
-                cand_valid = jnp.concatenate(
-                    [adv_valid, sel, probe_valid])
-                now_l = jnp.full((L,), now, jnp.float32)
-                zb, ob = jnp.zeros((L,), bool), jnp.ones((L,), bool)
-                cand = PktQ(
-                    flow=jnp.concatenate(
-                        [pop.flow[:2 * TS], lane_flow, lane_flow]),
-                    psn=jnp.concatenate(
-                        [pop.psn[:2 * TS], tx.psn, probe_tx.psn]),
-                    ts=jnp.concatenate(
-                        [pop.ts[:2 * TS], now_l, now_l]),
-                    probe=jnp.concatenate(
-                        [pop.probe[:2 * TS], zb, ob]),
-                    ecn=jnp.concatenate([ecn_out[:2 * TS], zb, zb]),
-                    ent=jnp.concatenate(
-                        [pop.ent[:2 * TS], ent, ent_probe]),
-                    ready=jnp.full((2 * TS + 2 * L,), 0, jnp.int32)
-                    + t + 1 + K,
-                    spine=jnp.concatenate(
-                        [pop.spine[:2 * TS], spine, spine_p]))
-                cand_bytes = jnp.concatenate([
-                    pop_bytes[:2 * TS],
-                    wire_bytes(lane_flow, tx.psn, zb),
-                    wire_bytes(lane_flow, probe_tx.psn, ob)])
-                M = 2 * TS + 2 * L
-                if M <= 256:
-                    tril = jnp.tril(jnp.ones((M, M), bool), k=-1)
-                    same_q = cand_qid[:, None] == cand_qid[None, :]
-
-                    def rank_among(flag):
-                        return jnp.sum(same_q & flag[None, :] & tril,
-                                       axis=1).astype(jnp.int32)
-                else:
-                    def rank_among(flag):
-                        return _rank_in_queue(cand_qid, flag, Q)
-                rank_v = rank_among(cand_valid)
-                occ = qsize[cand_qid] + rank_v
-                dropped = cand_valid & (
-                    ((~cand.probe) & (occ >= data_drop_pkts))
-                    | (occ >= hard_pkts))
-                accept = cand_valid & (~dropped)
-                rank_a = rank_among(accept)
-                pos = (qhead[cand_qid] + qsize[cand_qid] + rank_a) % cap
-                ownq = accept & (cand_qid >= qoff) \
-                    & (cand_qid < qoff + QRL)
-                flat_idx = jnp.where(
-                    ownq, (cand_qid - qoff) * cap + pos, QRL * cap)
-
-                def _wrow(f, v):
-                    flat = f.reshape(-1)
-                    pad1 = jnp.zeros((1,), f.dtype)
-                    out = jnp.concatenate([flat, pad1], 0).at[flat_idx]
-                    return out.set(v)[:QRL * cap].reshape(QRL, cap)
-
-                q = PktQ(*[_wrow(f, v) for f, v in zip(st.q, cand)])
-                added = jax.ops.segment_sum(
-                    accept.astype(jnp.int32),
-                    jnp.where(accept, cand_qid, Q), num_segments=Q + 1)
-                qsize = (qsize + added).at[Q].set(0)
-                qhead = qhead.at[Q].set(0)
-                drops = st.drops + jnp.sum(dropped).astype(jnp.int32)
-            else:
-                (q, qhead, qsize, pop, has, surv, ecn_out, pop_bytes,
-                 cand_qid, cand_bytes, accept, drops_add, bh_add,
-                 cor_add) = _serve(
-                    serve_enqueue_core,
-                    (st.q, st.qhead, st.qsize, paused_row, dst,
-                     dst_tor, total_pkts, tail_b, lane_flow, tx.psn,
-                     probe_tx.psn, ent, ent_probe, spine, spine_p, sel,
-                     probe_valid, inj_q, inj_qp, row_down, row_duty,
-                     row_cor_p, fseed, t))
-                fclip = jnp.clip(pop.flow, 0, N - 1)
-                drops = st.drops + drops_add
-
-            # ---- 3. deliveries -> per-flow receivers (one host = one q)
-            # (surv, not has: blackholed/corrupted packets left their
-            # buffer but never arrive)
-            del_has = surv[2 * TS:]
-            del_flow = fclip[2 * TS:]
-            slot_del = (t + dflow[del_flow]) % H
-            if DP > 1:
-                # receiver + return-pipe state live on the flow-owner
-                # pod: every pod walks the global delivery rows but
-                # gathers / commits only the flows it owns (trash row
-                # otherwise)
-                own = del_has & (del_flow >= foff) \
-                    & (del_flow < foff + NL)
-                lrow = jnp.where(own, del_flow - foff, NL)
-                rrows = _gather_rows(st.rcv, lrow, NL)
-                commit, fidx, n_lanes = own, lrow, NL
-            else:
-                rrows = jax.tree.map(lambda a: a[del_flow], st.rcv)
-                commit, fidx, n_lanes = del_has, del_flow, N
-            rnew, sack = jax.vmap(
-                lambda r, psn, sz, ecn, ent_, ts, pb: proto.on_data(
-                    r, psn, sz, ecn, ent_, ts, pb, now))(
-                rrows, pop.psn[2 * TS:], pop_bytes[2 * TS:],
-                ecn_out[2 * TS:], pop.ent[2 * TS:],
-                pop.ts[2 * TS:], pop.probe[2 * TS:])
-            rnew = _bwhere(commit, rnew, rrows)
-            rcv = _scatter_rows(st.rcv, rnew,
-                                jnp.where(commit, fidx, n_lanes),
-                                n_lanes)
-            delivered = _scatter_add(
-                st.delivered,
-                jnp.where(del_has & (~pop.probe[2 * TS:]), del_flow, N),
-                pop_bytes[2 * TS:], N)
-            # ECN observability: marked data packets counted at host
-            # delivery (outside the kernel cores, so identical across
-            # every lane formulation and kernel backend; warp-safe —
-            # skipped ticks deliver nothing)
-            ecn_add = jnp.sum(del_has & ecn_out[2 * TS:]
-                              & (~pop.probe[2 * TS:])).astype(jnp.int32)
-
-            # write emitted messages into the return pipe at slot
-            # t + D[flow]: each flow's ACK rides its own reverse path
-            # (never the slot the transport stage cleared this tick:
-            # 1 <= D[flow] <= H - 2)
-            sack_valid = sack.valid & commit
-            pipe = _scatter_pipe(pipe, sack._replace(valid=sack_valid),
-                                 slot_del, fidx, sack_valid, H, n_lanes)
-
-            # ---- 6b. PFC: per-ingress accounting + pause/resume masks ----
-            # Ingress attribution is derivable per packet: a packet's port
-            # at any switch follows from (flow src/dst, queue row, entropy),
-            # so the counters are maintained incrementally without storing
-            # a port field in the ring.  Accounting is per-packet WIRE
-            # bytes: odd tail packets and 64B probes count their real
-            # size, not a whole MTU (``events.Switch`` semantics).
-            if pfc:
-                # dequeues leaving a switch buffer
-                f_up, f_sd, f_hd = (fclip[:TS], fclip[TS:2 * TS],
-                                    fclip[2 * TS:])
-                ing_host = _scatter_add(
-                    st.ing_host, jnp.where(has[:TS], src[f_up], NH),
-                    -pop_bytes[:TS], NH)
-                sd_i = jnp.arange(TS, dtype=jnp.int32)
-                sd_s = sd_i // T   # spine of spine_down row TS + s*T + t
-                up_flat = st.ing_up.reshape(-1)
-                up_flat = _scatter_add(
-                    up_flat,
-                    jnp.where(has[TS:2 * TS], src_tor[f_sd] * S + sd_s, TS),
-                    -pop_bytes[TS:2 * TS], TS)
-                # the spine that handed the packet down is the ring's
-                # injection-time spine lane — re-deriving it from ECMP
-                # would diverge once fault schedules make the candidate
-                # masks time-varying
-                pkt_spine = pop.spine[2 * TS:]
-                hd_same = same_tor[f_hd]
-                served_hd = has[2 * TS:]
-                ing_host = _scatter_add(
-                    ing_host,
-                    jnp.where(served_hd & hd_same, src[f_hd], NH),
-                    -pop_bytes[2 * TS:], NH)
-                sd_flat = st.ing_sd.reshape(-1)
-                sd_flat = _scatter_add(
-                    sd_flat,
-                    jnp.where(served_hd & (~hd_same),
-                              pkt_spine * T + host_tor, TS),
-                    -pop_bytes[2 * TS:], TS)
-                # enqueues entering a switch buffer
-                up_i = jnp.arange(TS, dtype=jnp.int32)  # t*S+s of source row
-                up_flat = _scatter_add(
-                    up_flat, jnp.where(accept[:TS], up_i, TS),
-                    cand_bytes[:TS], TS)
-                sd_flat = _scatter_add(
-                    sd_flat, jnp.where(accept[TS:2 * TS], sd_i, TS),
-                    cand_bytes[TS:2 * TS], TS)
-                acc_data = accept[2 * TS:2 * TS + L]
-                acc_probe = accept[2 * TS + L:]
-                ing_host = _scatter_add(
-                    ing_host, jnp.where(acc_data, lane_src, NH),
-                    cand_bytes[2 * TS:2 * TS + L], NH)
-                ing_host = _scatter_add(
-                    ing_host, jnp.where(acc_probe, lane_src, NH),
-                    cand_bytes[2 * TS + L:], NH)
-                ing_sd = sd_flat.reshape(S, T)
-                ing_up = up_flat.reshape(T, S)
-
-                # byte-accurate shared-buffer occupancy (served bytes out,
-                # accepted bytes in) for the dynamic threshold
-                qbytes = st.qbytes.at[:Q].add(
-                    -jnp.where(has, pop_bytes, 0.0))
-                add_b = jax.ops.segment_sum(
-                    jnp.where(accept, cand_bytes, 0.0),
-                    jnp.where(accept, cand_qid, Q), num_segments=Q + 1)
-                qbytes = (qbytes + add_b).at[Q].set(0.0)
-                qsz_b = qbytes[:Q]
-                tor_occ = (qsz_b[:TS].reshape(T, S).sum(1)
-                           + qsz_b[2 * TS:].reshape(T, HPT).sum(1))
-                spine_occ = qsz_b[TS:2 * TS].reshape(S, T).sum(1)
-                a = cfg.pfc_alpha
-                xoff_tor = a * jnp.maximum(buffer_b - tor_occ, 0.0) / (1 + a)
-                xoff_spine = a * jnp.maximum(buffer_b - spine_occ, 0.0) \
-                    / (1 + a)
-
-                # the gate chains on the switch's DECISION state; the
-                # effective (upstream) state lags it by the pause-frame
-                # propagation delay via the pfc_line ring
-                paused_nic = pfc_gate(st.paused_nic, ing_host,
-                                      xoff_tor[host_tor], cfg.pfc_xon_frac)
-                paused_sd = pfc_gate(st.paused_sd, ing_sd,
-                                     xoff_tor[None, :], cfg.pfc_xon_frac)
-                paused_up = pfc_gate(st.paused_up, ing_up,
-                                     xoff_spine[None, :], cfg.pfc_xon_frac)
-                pauses = st.pauses + (
-                    jnp.sum(paused_nic & ~st.paused_nic)
-                    + jnp.sum(paused_sd & ~st.paused_sd)
-                    + jnp.sum(paused_up & ~st.paused_up)).astype(jnp.int32)
-                if PD > 0:
-                    dec = jnp.concatenate(
-                        [paused_nic, paused_sd.reshape(-1),
-                         paused_up.reshape(-1)])
-                    pfc_line = st.pfc_line.at[t % PD].set(dec)
-                else:
+                    qbytes = st.qbytes
+                    ing_host, ing_sd, ing_up = (st.ing_host, st.ing_sd,
+                                                st.ing_up)
+                    paused_nic, paused_sd, paused_up = (
+                        st.paused_nic, st.paused_sd, st.paused_up)
                     pfc_line = st.pfc_line
-            else:
-                qbytes = st.qbytes
-                ing_host, ing_sd, ing_up = (st.ing_host, st.ing_sd,
-                                            st.ing_up)
-                paused_nic, paused_sd, paused_up = (
-                    st.paused_nic, st.paused_sd, st.paused_up)
-                pfc_line = st.pfc_line
-                pauses = st.pauses
+                    pauses = st.pauses
 
-            # ---- 7. completion + metrics --------------------------------
-            if DP > 1:
-                done = jax.lax.all_gather(
-                    jax.vmap(proto.done)(flows), "pod", tiled=True)
-            elif A:
-                # done lanes update in place from the core's per-lane
-                # done bits (see active_trans_core)
-                done = _set_rows(
-                    done_prev, jnp.where(lane_ok, act_idx, N),
-                    done_lane, N)
-            else:
-                done = jax.vmap(proto.done)(flows)
-            done_tick = jnp.where(done & (st.done_tick < 0),
-                                  t.astype(jnp.int32), st.done_tick)
+            with jax.named_scope("fabric.complete"):
+                # ---- 7. completion + metrics --------------------------------
+                if DP > 1:
+                    done = jax.lax.all_gather(
+                        jax.vmap(proto.done)(flows), "pod", tiled=True)
+                elif A:
+                    # done lanes update in place from the core's per-lane
+                    # done bits (see active_trans_core)
+                    done = _set_rows(
+                        done_prev, jnp.where(lane_ok, act_idx, N),
+                        done_lane, N)
+                else:
+                    done = jax.vmap(proto.done)(flows)
+                done_tick = jnp.where(done & (st.done_tick < 0),
+                                      t.astype(jnp.int32), st.done_tick)
 
-            # message completion: all sub-flows done; newly-completed
-            # messages decrement their children's pending-dep counters
-            # (the children become sendable NEXT tick, step 0 above)
-            undone = jax.ops.segment_sum((~done).astype(jnp.int32),
-                                         dep.msg_of_flow,
-                                         num_segments=n_msgs)
-            msg_done = undone == 0
-            newly = msg_done & (~st.msg_done)
-            if n_edges > 0:
-                dec = jax.ops.segment_sum(
-                    newly[dep.edge_parent].astype(jnp.int32),
-                    dep.edge_child, num_segments=n_msgs)
-                pending = st.pending - dec
-            else:
-                pending = st.pending
-            msg_done_tick = jnp.where(newly, t.astype(jnp.int32),
-                                      st.msg_done_tick)
-            g_undone = jax.ops.segment_sum((~msg_done).astype(jnp.int32),
-                                           dep.group_of_msg,
-                                           num_segments=n_groups)
-            group_done_tick = jnp.where(
-                (g_undone == 0) & (st.group_done_tick < 0),
-                t.astype(jnp.int32), st.group_done_tick)
+                # message completion: all sub-flows done; newly-completed
+                # messages decrement their children's pending-dep counters
+                # (the children become sendable NEXT tick, step 0 above)
+                undone = jax.ops.segment_sum((~done).astype(jnp.int32),
+                                             dep.msg_of_flow,
+                                             num_segments=n_msgs)
+                msg_done = undone == 0
+                newly = msg_done & (~st.msg_done)
+                if n_edges > 0:
+                    dec = jax.ops.segment_sum(
+                        newly[dep.edge_parent].astype(jnp.int32),
+                        dep.edge_child, num_segments=n_msgs)
+                    pending = st.pending - dec
+                else:
+                    pending = st.pending
+                msg_done_tick = jnp.where(newly, t.astype(jnp.int32),
+                                          st.msg_done_tick)
+                g_undone = jax.ops.segment_sum((~msg_done).astype(jnp.int32),
+                                               dep.group_of_msg,
+                                               num_segments=n_groups)
+                group_done_tick = jnp.where(
+                    (g_undone == 0) & (st.group_done_tick < 0),
+                    t.astype(jnp.int32), st.group_done_tick)
 
-            # chaos observability: accepted data injections per target
-            # row (the entropy-shift gates read this) + per-flap-window
-            # retransmit attribution.  Both are exact on warp runs:
-            # skipped ticks inject nothing.
-            acc_data_l = accept[2 * TS:2 * TS + L]
-            tx_rows = st.tx_rows.at[
-                jnp.where(acc_data_l, inj_q, Q)].add(1)
-            if FW > 0:
-                in_win = (fd.win_t0 <= ti) \
-                    & (ti < fd.win_t1 + 2 * rto_ticks)
-                win_retx = st.win_retx + jnp.where(in_win, rtx_n, 0)
-            else:
-                win_retx = st.win_retx
+                # chaos observability: accepted data injections per target
+                # row (the entropy-shift gates read this) + per-flap-window
+                # retransmit attribution.  Both are exact on warp runs:
+                # skipped ticks inject nothing.
+                acc_data_l = accept[2 * TS:2 * TS + L]
+                tx_rows = st.tx_rows.at[
+                    jnp.where(acc_data_l, inj_q, Q)].add(1)
+                if FW > 0:
+                    in_win = (fd.win_t0 <= ti) \
+                        & (ti < fd.win_t1 + 2 * rto_ticks)
+                    win_retx = st.win_retx + jnp.where(in_win, rtx_n, 0)
+                else:
+                    win_retx = st.win_retx
 
-            new_st = FabricState(
-                flows=flows, rcv=rcv, q=q, qhead=qhead, qsize=qsize,
-                pipe=pipe, obl_rr=obl_rr, drops=drops, delivered=delivered,
-                done_tick=done_tick, qbytes=qbytes, ing_host=ing_host,
-                ing_sd=ing_sd, ing_up=ing_up, paused_nic=paused_nic,
-                paused_sd=paused_sd, paused_up=paused_up,
-                pfc_line=pfc_line, pauses=pauses,
-                pending=pending, msg_done=msg_done,
-                msg_release_tick=msg_release_tick,
-                msg_done_tick=msg_done_tick,
-                group_done_tick=group_done_tick,
-                act_overflow=st.act_overflow + overflow,
-                ecn_marks=st.ecn_marks + ecn_add,
-                # post-enqueue depth max; identity on warp-skipped ticks
-                qdepth_hi=jnp.maximum(st.qdepth_hi, qsize),
-                blackholed=st.blackholed + bh_add + bh_nic,
-                corrupt_drops=st.corrupt_drops + cor_add,
-                tx_rows=tx_rows, win_retx=win_retx)
-            return new_st, jnp.any(can_tx)
+                new_st = FabricState(
+                    flows=flows, rcv=rcv, q=q, qhead=qhead, qsize=qsize,
+                    pipe=pipe, obl_rr=obl_rr, drops=drops, delivered=delivered,
+                    done_tick=done_tick, qbytes=qbytes, ing_host=ing_host,
+                    ing_sd=ing_sd, ing_up=ing_up, paused_nic=paused_nic,
+                    paused_sd=paused_sd, paused_up=paused_up,
+                    pfc_line=pfc_line, pauses=pauses,
+                    pending=pending, msg_done=msg_done,
+                    msg_release_tick=msg_release_tick,
+                    msg_done_tick=msg_done_tick,
+                    group_done_tick=group_done_tick,
+                    act_overflow=st.act_overflow + overflow,
+                    ecn_marks=st.ecn_marks + ecn_add,
+                    # post-enqueue depth max; identity on warp-skipped ticks
+                    qdepth_hi=jnp.maximum(st.qdepth_hi, qsize),
+                    blackholed=st.blackholed + bh_add + bh_nic,
+                    corrupt_drops=st.corrupt_drops + cor_add,
+                    tx_rows=tx_rows, win_retx=win_retx)
+            with jax.named_scope("fabric.warp"):
+                can_any = jnp.any(can_tx)
+            return new_st, can_any
 
         def snapshot(st: FabricState) -> dict:
             """Per-tick trace row, derived purely from state (so dense and
@@ -2034,20 +2055,24 @@ def _make_program(topo: FatTree, n_flows: int, n_ticks: int,
                 # departure-lane arrival), no freshly-released message
                 # still needs its release tick recorded, and the PFC
                 # pause-frame delay line holds no in-flight transition.
-                idle = ((~can_any)
-                        & ~jnp.any((st.pending <= 0) & (arrival <= t)
-                                   & (st.msg_release_tick < 0)))
-                if pfc and PD > 0:
-                    dec = jnp.concatenate(
-                        [st.paused_nic, st.paused_sd.reshape(-1),
-                         st.paused_up.reshape(-1)])
-                    idle = idle & jnp.all(st.pfc_line == dec[None, :])
-                t_next = jnp.where(idle, warp_target(st, t), t + 1)
+                with jax.named_scope("fabric.warp"):
+                    idle = ((~can_any)
+                            & ~jnp.any((st.pending <= 0) & (arrival <= t)
+                                       & (st.msg_release_tick < 0)))
+                    if pfc and PD > 0:
+                        dec = jnp.concatenate(
+                            [st.paused_nic, st.paused_sd.reshape(-1),
+                             st.paused_up.reshape(-1)])
+                        idle = idle & jnp.all(st.pfc_line == dec[None, :])
+                    t_next = jnp.where(idle, warp_target(st, t), t + 1)
                 return t_next, st, trips + jnp.int32(1)
 
+            def cond(carry):
+                with jax.named_scope("fabric.cond"):
+                    return carry[0] < n_ticks
+
             end_t, final, trips = jax.lax.while_loop(
-                lambda c: c[0] < n_ticks, trip,
-                (jnp.int32(0), st0, jnp.int32(0)))
+                cond, trip, (jnp.int32(0), st0, jnp.int32(0)))
             return final, {"warp_trips": trips, "end_tick": end_t}
 
         if trace_every == 0:
@@ -2113,6 +2138,10 @@ def _make_program(topo: FatTree, n_flows: int, n_ticks: int,
                            arrival, fd)
     else:
         program = body
+    # the jitted entry's name: the XLA module is jit_fabric_program, every
+    # op's scope path in a device trace starts with it, and the compile
+    # counters of obs/spans.py count its compiles only
+    program.__name__ = program.__qualname__ = spans.PROGRAM
     program.dims = dict(T=T, S=S, NH=NH, TS=TS, Q=Q, cap=cap, H=H,
                         K=K, D_same=D_same, D_cross=D_cross, PD=PD,
                         shard=DP, active_cap=A)
@@ -2186,6 +2215,7 @@ def _get_program(topo: FatTree, n_flows: int, n_ticks: int,
     key = _program_key(topo, n_flows, n_ticks, cfg, dep) + (n_real,)
     prog = _PROGRAM_CACHE.get(key)
     if prog is None:
+        spans.listen()  # count this program's first trace and compile
         program = _make_program(topo, n_flows, n_ticks, cfg, dep,
                                 n_real=n_real)
         # the batch axis vmaps the flow-array inputs; the fault schedule
@@ -2439,33 +2469,41 @@ def run_fabric_trace(topo: FatTree, messages, n_ticks: int,
     Programs are cached on the static dims — repeated same-shape calls
     (benchmark seed loops, parity pairs) trace and compile exactly once.
     """
-    flows, dep = expand_messages(messages, cfg.subflows)
-    _check_flows(flows, topo.n_hosts)
-    if cfg.faults is not None:
-        validate_faults(cfg.faults, topo)
-    fd = build_fault_data(cfg.faults, topo.n_tor, topo.n_spine,
-                          topo.hosts_per_tor)
-    arrs = _flow_arrays(flows, cfg)
-    arrival = _arrival_array(messages)
-    dep_run, n_real = dep, None
-    if int(cfg.shard) > 1:
-        arrs, dep_run, n_real = _shard_pad_inputs(
-            flows, dep, arrs, cfg, topo.n_hosts)
-        arrival = jnp.concatenate([
-            arrival, jnp.zeros((dep_run.n_msgs - dep.n_msgs,), jnp.int32)])
-    src, dst, total_pkts, tails, ent0 = arrs
-    prog = _get_program(topo, int(src.shape[0]), n_ticks, cfg, dep_run,
-                        n_real=n_real)
-    lb = jnp.int32(LB_MODES.index(cfg.lb_mode))
-    final, metrics = prog.jit_single(src, dst, total_pkts, tails, ent0, lb,
-                                     arrival, fd)
-    proto, _, _, _ = _make_protocol(cfg)
-    fin = _final_host(final)
-    fin["retx"] = jax.device_get(proto.stat_retx(final.flows))
-    fin.update(jax.device_get(proto.stat_recovery(final.flows)))
-    if n_real is not None:
-        fin = _slice_fin(fin, n_real, dep.n_msgs, dep.n_groups)
-    metrics = _finish_metrics(dict(metrics), fin, cfg, prog.dims, dep)
+    with spans.span("fabric.inputs"):
+        flows, dep = expand_messages(messages, cfg.subflows)
+        _check_flows(flows, topo.n_hosts)
+        if cfg.faults is not None:
+            validate_faults(cfg.faults, topo)
+        fd = build_fault_data(cfg.faults, topo.n_tor, topo.n_spine,
+                              topo.hosts_per_tor)
+        arrs = _flow_arrays(flows, cfg)
+        arrival = _arrival_array(messages)
+        dep_run, n_real = dep, None
+        if int(cfg.shard) > 1:
+            arrs, dep_run, n_real = _shard_pad_inputs(
+                flows, dep, arrs, cfg, topo.n_hosts)
+            arrival = jnp.concatenate([
+                arrival,
+                jnp.zeros((dep_run.n_msgs - dep.n_msgs,), jnp.int32)])
+        src, dst, total_pkts, tails, ent0 = arrs
+    with spans.span("fabric.program"):
+        prog = _get_program(topo, int(src.shape[0]), n_ticks, cfg, dep_run,
+                            n_real=n_real)
+    with spans.span("fabric.dispatch"):
+        lb = jnp.int32(LB_MODES.index(cfg.lb_mode))
+        final, metrics = prog.jit_single(src, dst, total_pkts, tails, ent0,
+                                         lb, arrival, fd)
+        proto, _, _, _ = _make_protocol(cfg)  # overlaps the device
+    with spans.span("fabric.device"):
+        jax.block_until_ready((final, metrics))
+    with spans.span("fabric.fetch"):
+        fin = _final_host(final)
+        fin["retx"] = jax.device_get(proto.stat_retx(final.flows))
+        fin.update(jax.device_get(proto.stat_recovery(final.flows)))
+    with spans.span("fabric.summary"):
+        if n_real is not None:
+            fin = _slice_fin(fin, n_real, dep.n_msgs, dep.n_groups)
+        metrics = _finish_metrics(dict(metrics), fin, cfg, prog.dims, dep)
     return final, metrics
 
 

@@ -51,6 +51,7 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 from ..core.params import NetworkSpec, make_roce_params
+from ..obs import spans
 from .events import NetSim
 from .fabric import (FabricConfig, _rto_us, run_fabric_trace,
                      run_fabric_trace_batch, summarize)
@@ -576,10 +577,14 @@ def _fabric_summary(sc: Scenario, cfg: RunConfig, metrics: dict) -> dict:
 
 
 def _run_fabric_backend(sc: Scenario, cfg: RunConfig) -> dict:
-    fcfg = _fabric_cfg(sc, cfg)
-    _, metrics = run_fabric_trace(sc.topo, sc.messages,
-                                  _scenario_ticks(sc, cfg), fcfg)
-    return _fabric_summary(sc, cfg, metrics)
+    answer = spans.next_answer()
+    with spans.span("fabric.run", answer=answer):
+        fcfg = _fabric_cfg(sc, cfg)
+        _, metrics = run_fabric_trace(sc.topo, sc.messages,
+                                      _scenario_ticks(sc, cfg), fcfg)
+        out = _fabric_summary(sc, cfg, metrics)
+    out["answer"] = answer  # the id of this call's spans (obs/spans.py)
+    return out
 
 
 def _events_sim(sc: Scenario, cfg: RunConfig, **netsim_kw) -> NetSim:
