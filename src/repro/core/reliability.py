@@ -59,12 +59,43 @@ def init_receiver(total_pkts) -> ReceiverState:
     )
 
 
-def _shift_left(bitmap: jax.Array, shift: jax.Array) -> jax.Array:
-    """bitmap <<= shift, zero-filled (shift is traced)."""
+def _shift(bitmap: jax.Array, shift: jax.Array, left: bool) -> jax.Array:
+    """``bitmap`` moved by a traced ``shift`` in 0..len(bitmap), zero-filled:
+    towards bit 0 if ``left``, else away from it (bits pushed past the end
+    are lost).
+
+    A log-step barrel shifter of static slices.  Under vmap a slice at a
+    traced offset (``jnp.roll``, ``dynamic_slice``) is a gather, and the TPU
+    compiler turns that gather into a serial loop over the rows."""
     n = bitmap.shape[0]
-    rolled = jnp.roll(bitmap, -shift)
-    keep = jnp.arange(n) < (n - shift)
-    return rolled & keep
+    for k in range(n.bit_length()):
+        b = 1 << k
+        fill = jnp.zeros((b,), bitmap.dtype)
+        moved = (jnp.concatenate([bitmap[b:], fill]) if left
+                 else jnp.concatenate([fill, bitmap[:n - b]]))
+        bitmap = jnp.where((shift & b) != 0, moved, bitmap)
+    return bitmap
+
+
+def _shift_left(bitmap: jax.Array, shift: jax.Array) -> jax.Array:
+    """bitmap <<= shift, zero-filled (shift is traced, 0..len(bitmap))."""
+    return _shift(bitmap, shift, left=True)
+
+
+def _sack_segment(bitmap: jax.Array, off: jax.Array,
+                  nbits: int) -> jax.Array:
+    """The ``nbits`` bits of ``bitmap`` from ``off`` >= 0 on, zeros past
+    its end (``off`` is clamped to len(bitmap), as ``dynamic_slice``
+    clamps)."""
+    padded = jnp.concatenate([bitmap, jnp.zeros((nbits,), bitmap.dtype)])
+    return _shift_left(padded, jnp.clip(off, 0, bitmap.shape[0]))[:nbits]
+
+
+def _place_segment(bits: jax.Array, off: jax.Array, width: int) -> jax.Array:
+    """A ``width``-bit map holding ``bits`` from ``off`` on (bits past its
+    end are lost); ``off`` is clamped to 0..width."""
+    padded = jnp.concatenate([bits, jnp.zeros((width,), bits.dtype)])[:width]
+    return _shift(padded, jnp.clip(off, 0, width), left=False)
 
 
 def receiver_on_data(rs: ReceiverState, p: STrackParams, psn: jax.Array,
@@ -103,8 +134,7 @@ def receiver_on_data(rs: ReceiverState, p: STrackParams, psn: jax.Array,
     seg = (lpsn_eff - epsn) // p.sack_bitmap_bits
     base = epsn + seg * p.sack_bitmap_bits
     off = base - epsn
-    padded = jnp.concatenate([bitmap, jnp.zeros((p.sack_bitmap_bits,), bool)])
-    sack_bits = jax.lax.dynamic_slice(padded, (off,), (p.sack_bitmap_bits,))
+    sack_bits = _sack_segment(bitmap, off, p.sack_bitmap_bits)
 
     sack = SackMsg(
         valid=trigger,
@@ -248,11 +278,7 @@ def rel_on_sack(rel: RelState, p: STrackParams, sack: SackMsg,
 
     # --- selective bits ---
     off = sack.sack_base - epsn  # may be negative (stale segment)
-    bits = sack.sack_bits
-    nbits = bits.shape[0]
-    placed = jnp.zeros((W + nbits,), bool)
-    placed = jax.lax.dynamic_update_slice(
-        placed, bits, (jnp.clip(off, 0, W),))[:W]
+    placed = _place_segment(sack.sack_bits, off, W)
     placed = placed & (off >= 0)  # drop stale segments entirely for safety
     newly = placed & (~sacked)
     unclaim_sel = newly & claimed

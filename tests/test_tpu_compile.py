@@ -10,6 +10,7 @@ import, so every pytest-xdist worker collects the same tests and only the
 worker that runs this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,9 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from benchmarks.perf import canonical_scenarios
+from repro.core import reliability as rel
+from repro.core import transport as tp
+from repro.core.params import NetworkSpec, make_strack_params
 from repro.kernels.fabric_kernels import rank_in_queue_kernel
 from repro.sim import fabric
 from repro.sim.faults import build_fault_data
@@ -24,6 +28,8 @@ from repro.sim.workloads import RunConfig, _fabric_cfg, _scenario_ticks
 
 #: HBM of one TPU v5e chip (Google Cloud documentation, "TPU v5e").
 V5E_HBM_BYTES = 16 * 10 ** 9
+#: Flows of the 8192-host cells, one reorder-bitmap row each.
+N_FLOWS = 8192
 
 
 @pytest.fixture(scope="module")
@@ -97,3 +103,34 @@ def test_fused_stage_kernels_refuse_tpu_lowering(v5e_chip):
     rc = RunConfig(backend="fabric", kernel_backend="pallas")
     with pytest.raises(NotImplementedError, match="scatter"):
         _lower_fabric(sc, rc, v5e_chip).compile()
+
+
+def _reliability_site(site: str):
+    """``(vmapped function, its argument pytree)`` of one per-flow STrack
+    entry point that slides the reorder bitmaps, at one flow's shapes."""
+    p = make_strack_params(NetworkSpec(), max_paths=64)
+    rs = rel.init_receiver(10)
+    if site == "receiver_on_data":
+        fn = jax.vmap(lambda r, psn, size, ecn, ent, ts, probe:
+                      rel.receiver_on_data(r, p, psn, size, ecn, ent, ts,
+                                           probe))
+        return fn, (rs, jnp.int32(0), jnp.float32(0), jnp.bool_(False),
+                    jnp.int32(0), jnp.float32(0), jnp.bool_(False))
+    _, sack = rel.receiver_on_data(rs, p, 0, 4096.0, False, 0, 0.0, False)
+    fn = jax.vmap(lambda f, m, now: tp.flow_on_sack(f, p, m, now))
+    return fn, (tp.init_flow(p, 10), sack, jnp.float32(0))
+
+
+@pytest.mark.parametrize("site", ["flow_on_sack", "receiver_on_data"])
+def test_reorder_bitmaps_compile_without_row_loops(v5e_chip, site):
+    """vmap of the SACK and data handlers over 8192 flows compiles for v5e
+    with no HLO while loop.  A reorder bitmap slid or windowed at a traced
+    offset is a gather under vmap, which the TPU compiler expands into a
+    serial loop over the rows."""
+    fn, one = _reliability_site(site)
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((N_FLOWS,) + jnp.shape(a),
+                                       jnp.asarray(a).dtype,
+                                       sharding=v5e_chip), one)
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert re.search(r"\swhile\(", hlo) is None
